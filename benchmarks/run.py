@@ -14,6 +14,9 @@ def main() -> None:
                     help="substring filter on benchmark names")
     args = ap.parse_args()
 
+    from repro.launch.train import use_checkout_compile_cache
+    use_checkout_compile_cache()
+
     from benchmarks import (dp_bench, extensions_bench, figures,
                             kernels_bench, obs_bench, rounds_bench,
                             scale_bench)

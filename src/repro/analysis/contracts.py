@@ -19,8 +19,9 @@ no pointwise test can see:
   (DESIGN.md §15).  Without ``dp=`` the body must contain no normal
   draw at all.
 * **collective axes** — every ``psum``/``all_gather``/… axis name is ⊆
-  the active topology's mesh axes; the local topology compiles to zero
-  collectives.
+  the active topology's mesh axes (the ``*_invariant`` variants that
+  ``shard_map(check_vma=True)`` binds included); the local topology
+  compiles to zero collectives.
 * **wire dtypes** — ``codec.encode`` output dtypes equal the codec's
   wire spec (int8 values + f32 scales for the quantizer, f32 for
   identity/dense), via ``jax.eval_shape``.
@@ -169,8 +170,9 @@ def check_dp_before_encode(body, dp_on: bool, int8: bool) -> list[str]:
 def check_collective_axes(body, allowed: tuple[str, ...]) -> list[str]:
     out = []
     for eqn in _iter_eqns(body):
-        # versioned primitive names: psum lowered as psum2 on this jax
-        base = eqn.primitive.name.rstrip("0123456789")
+        # shard_map with check_vma binds the replication-typed variants
+        # (psum_invariant, all_gather_invariant, ...) of each collective
+        base = eqn.primitive.name.removesuffix("_invariant")
         if base not in _COLLECTIVE_PRIMS:
             continue
         axes = eqn.params.get("axes", eqn.params.get("axis_name", ()))

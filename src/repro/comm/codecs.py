@@ -47,8 +47,10 @@ IDX_BYTES = 4      # int32 coordinate per kept entry (top-k wire format)
 
 def uniform_from_bits(bits):
     """uint32 random bits -> Uniform[0,1) with 24-bit mantissa precision.
-    Identical to the Pallas kernel's formula so ref == kernel exactly."""
-    return ((bits >> jnp.uint32(8)).astype(jnp.float32)
+    Identical to the Pallas kernel's formula so ref == kernel exactly. The
+    top 24 bits fit an int32, so converting through int32 is exact — and it
+    is the integer->float conversion Mosaic lowers (it refuses uint32)."""
+    return ((bits >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
             * jnp.float32(1.0 / (1 << 24)))
 
 

@@ -43,7 +43,6 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
-import jax
 import jax.numpy as jnp
 
 
@@ -94,24 +93,10 @@ class EFStore(NamedTuple):
         return self._replace(data=self.data.at[ids].set(rows))
 
 
-def ef_store_init(num_clients: int, dim: int,
-                  host_offload: bool = False) -> EFStore:
-    """Zero-initialized keyed residual store for `fed.cohort_round`.
-
-    ``host_offload=True`` places the backing in the backend's pinned host
-    memory space when one exists (the (I, P) matrix at I = 1e6 can exceed
-    accelerator HBM); gather/scatter keep working behind the identical
-    interface — XLA stages the (S, P) slices through device memory. Falls
-    back to default device placement (with no error) on backends without a
-    pinned_host memory space, so callers never branch."""
-    data = jnp.zeros((num_clients, dim), jnp.float32)
-    if host_offload:
-        try:
-            mem = jax.devices()[0].memory("pinned_host")
-            data = jax.device_put(data, mem)
-        except Exception:       # backend has no pinned_host space — stay put
-            pass
-    return EFStore(data=data)
+def ef_store_init(num_clients: int, dim: int) -> EFStore:
+    """Zero-initialized keyed residual store for `fed.cohort_round`, its
+    (I, P) backing in device memory."""
+    return EFStore(data=jnp.zeros((num_clients, dim), jnp.float32))
 
 
 def with_comm_carry(codec, body):

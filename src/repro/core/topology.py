@@ -23,7 +23,7 @@ The two realizations:
   client axis, `jnp.tensordot` for the server sum. Bit-for-bit the engine
   the repo has always run; kept as the equivalence reference.
 * :class:`ShardedTopology` — clients distributed over the mesh's
-  ("pod","data") axes via `jax.experimental.shard_map`: each device vmaps
+  ("pod","data") axes via `jax.shard_map`: each device vmaps
   its I/D resident clients, applies the codec encode + error-feedback
   residual update *per shard before any collective* (compression happens at
   the client boundary, exactly as in the simulation), reduces its local
@@ -71,7 +71,6 @@ from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.comm import codecs as comm_codecs
@@ -352,11 +351,11 @@ class ShardedTopology:
                 value = jax.lax.psum(val_partial, axes)
             return weighted, value, uploads, values, enc, new_ef, dp_stats
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(spec, spec, spec, spec, spec, spec, spec),
             out_specs=(P(), P(), spec, spec, spec, spec, spec),
-            check_rep=False)
+            check_vma=False)
         weighted, value, uploads, values, enc, new_ef, dp_stats = sharded(
             tuple(args), weights, ef, codec_keys, active, dp_keys, dp_scale)
         return ClientSums(weighted=weighted, value=value, uploads=uploads,
@@ -430,12 +429,12 @@ class ShardedTopology:
                         codec, q_head, q_blocks, ef_l, hkey, bkeys_l)
             return h_l, h_sum, value, q_head, q_blocks, enc, new_ef, dp_stats
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(spec, spec, ef_spec, keys_spec, P(), dp_keys_spec, P()),
             out_specs=(spec, P(), P(), P(), spec, enc_spec, ef_out_spec,
                        dp_out_spec),
-            check_rep=False)
+            check_vma=False)
         h, h_sum, value, q_head, q_blocks, enc, new_ef, dp_stats = sharded(
             blocks, zb, ef, block_keys, head_key, dp_block_keys, dp_head_key)
         return FeatureSums(h=h, h_sum=h_sum, value=value, q_head=q_head,

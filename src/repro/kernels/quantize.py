@@ -48,14 +48,14 @@ def _qdq_kernel(sc_ref, x_ref, *rest, qmax: int, device_prng: bool):
         bits = bits_ref[...]
     x = x_ref[...].astype(jnp.float32)
     u = uniform_from_bits(bits)     # single-sourced: codec ref == kernel
-    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)     # (rows, 1)
     # explicit reciprocal-multiply, matching codecs.stochastic_round_chunks
     # exactly (XLA strength-reduces /const inconsistently across contexts)
     scale = absmax * jnp.float32(1.0 / qmax)
     safe = jnp.where(scale > 0, scale, 1.0)
     q = jnp.clip(jnp.floor(x / safe + u), -qmax, qmax)
     v_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale[:, 0]
+    s_ref[...] = scale
     xh_ref[...] = q * scale
 
 
@@ -97,14 +97,17 @@ def stochastic_quantize_pallas(x, qmax: int, chunk: int = 256, *,
             num_scalar_prefetch=1,
             grid=(padded_rows // rows,),
             in_specs=in_specs,
+            # scales are a (rows, 1) column, not a 1-D (rows,) block: under
+            # vmap (one call per client) the block gains a leading squeezed
+            # axis, and a 1-D block would then break the (8, 128) tiling rule
             out_specs=[pl.BlockSpec((rows, chunk), lambda i, sc: (i, 0)),
-                       pl.BlockSpec((rows,), lambda i, sc: (i,)),
+                       pl.BlockSpec((rows, 1), lambda i, sc: (i, 0)),
                        pl.BlockSpec((rows, chunk), lambda i, sc: (i, 0))],
         ),
         out_shape=[jax.ShapeDtypeStruct((padded_rows, chunk), jnp.int8),
-                   jax.ShapeDtypeStruct((padded_rows,), jnp.float32),
+                   jax.ShapeDtypeStruct((padded_rows, 1), jnp.float32),
                    jax.ShapeDtypeStruct((padded_rows, chunk), jnp.float32)],
         interpret=interpret,
     )(scalars, *operands)
-    return (v.reshape(-1)[: num_chunks * chunk], s[:num_chunks],
+    return (v.reshape(-1)[: num_chunks * chunk], s[:num_chunks, 0],
             xh.reshape(-1)[:p])

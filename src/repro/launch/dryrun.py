@@ -74,7 +74,7 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     fl = fl or FLConfig(tau=0.2, l2_lambda=1e-5)
     t0 = time.time()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             batch = shapes_lib.train_specs(cfg, shape)
             state = _state_shapes(model, cfg, constrained)

@@ -71,8 +71,10 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import os
 import time
-from typing import Optional
+from pathlib import Path
+from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -81,7 +83,7 @@ from jax.sharding import PartitionSpec as P
 from repro.comm import (CommCarry, ef_init, ef_init_stacked, ef_roundtrip,
                         flatten_tree, make_codec, tree_flat_dim,
                         with_comm_carry)
-from repro.configs import FLConfig, get_config
+from repro.configs import FLConfig, ModelConfig, get_config
 from repro.core import optimizer, rounds
 from repro.core import privacy as privacy_lib
 from repro.core import topology as topology_lib
@@ -90,6 +92,20 @@ from repro.models import get_model
 from repro.obs import metrics as obs_metrics
 from repro.obs import sinks as obs_sinks
 from repro.obs import trace as obs_trace
+
+
+def use_checkout_compile_cache():
+    """Entry-point setup of JAX's persistent compilation cache: a fixed
+    ``<checkout>/.jax_cache`` unless ``JAX_COMPILATION_CACHE_DIR`` is set,
+    in which case JAX already uses that directory and nothing is changed.
+    The path is fixed (never temporary, per-process or per-run) so that a
+    later run from the same checkout finds what an earlier one compiled.
+    Call it from ``main``-level code only: importing the library
+    configures nothing."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(Path(__file__).resolve().parents[3]
+                              / ".jax_cache"))
 
 
 def _make_stream(log_jsonl, log_stream_every, profile_dir, name):
@@ -274,7 +290,8 @@ def make_scanned_step(model, cfg, fl: FLConfig, tokens, batch: int, seq: int,
     return with_comm_carry(codec, comm_body)
 
 
-def train_loop(arch: str, steps: int, batch: int, seq: int, *,
+def train_loop(arch: Union[str, ModelConfig], steps: int, batch: int,
+               seq: int, *,
                smoke: bool = False, constrained: bool = False,
                fl: Optional[FLConfig] = None, log_every: int = 10,
                ckpt_path: Optional[str] = None, seed: int = 0,
@@ -284,9 +301,15 @@ def train_loop(arch: str, steps: int, batch: int, seq: int, *,
                log_jsonl: Optional[str] = None, log_stream_every: int = 1,
                profile_dir: Optional[str] = None,
                dp: Optional[privacy_lib.DPConfig] = None):
+    """Train a zoo LM with the SSCA optimizer; ``arch`` is a registry name
+    or a `ModelConfig` (e.g. a depth-cut published config). Returns the
+    final state and one log row per ``log_every``-step dispatch."""
     from repro.data.synthetic import token_dataset
 
-    cfg = get_config(arch)
+    if isinstance(arch, ModelConfig):
+        cfg, arch = arch, arch.name
+    else:
+        cfg = get_config(arch)
     if smoke:
         cfg = cfg.smoke()
     fl = fl or FLConfig(a1=0.9, a2=0.5, alpha_rho=0.1, alpha_gamma=0.6,
@@ -605,6 +628,7 @@ def main():
                     help="jax.profiler trace of the whole run into DIR "
                          "(phase-annotated; open with xprof/perfetto)")
     args = ap.parse_args()
+    use_checkout_compile_cache()
     dp = (privacy_lib.DPConfig(clip_norm=args.dp_clip,
                                epsilon=args.dp_epsilon, delta=args.dp_delta)
           if args.dp_epsilon is not None else None)

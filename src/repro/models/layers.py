@@ -20,16 +20,13 @@ from jax.sharding import PartitionSpec as P
 
 
 def _ambient_mesh():
-    try:
-        from jax._src import mesh as _mesh_lib
-        m = _mesh_lib.thread_resources.env.physical_mesh
-        return m if m.axis_names else None
-    except Exception:
-        return None
+    """The mesh set by `jax.set_mesh` around the call, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _ambient_axes():
-    """Axis names of the mesh in context (legacy `with mesh:` or none)."""
+    """Axis names of the mesh in context (`jax.set_mesh`), or ()."""
     m = _ambient_mesh()
     return tuple(m.axis_names) if m is not None else ()
 
@@ -551,7 +548,6 @@ def moe_expert_parallel(params, x, cfg):
     mesh = _ambient_mesh()
     if mesh is None or "model" not in mesh.axis_names:
         return moe(params, x, cfg)
-    from jax.experimental.shard_map import shard_map
 
     m_size = mesh.shape["model"]
     if cfg.n_experts % m_size:
@@ -605,11 +601,11 @@ def moe_expert_parallel(params, x, cfg):
     dense = params.get("dense")
     dense_spec = (jax.tree.map(lambda _: P(None, None), dense)
                   if dense is not None else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, None), P("model", None, None), P("model", None, None),
                   P("model", None, None), dense_spec, pspec),
         out_specs=(pspec, P()),
-        check_rep=False)
+        check_vma=False)
     return fn(params["router"], params["wi"], params["wg"], params["wo"],
               dense, x)

@@ -75,7 +75,10 @@ def _row_floats(names, vec) -> dict:
 def _drain_loop(stream_ref, q):
     """Daemon drainer: builds rows from raw flushes and feeds the sinks,
     off the dispatch thread. Holds only a weakref to the stream so the
-    thread cannot keep it alive; exits once the stream is collected."""
+    thread cannot keep it alive; exits once the stream is collected. An
+    exception (a failed device computation, a sink that cannot write) is
+    kept on the stream and re-raised by :meth:`MetricStream.sync`; the
+    thread keeps consuming so ``sync`` never waits on a dead drainer."""
     while True:
         try:
             kind, *item = q.get(timeout=1.0)
@@ -83,16 +86,19 @@ def _drain_loop(stream_ref, q):
             if stream_ref() is None:
                 return
             continue
+        stream = stream_ref()
         try:
-            stream = stream_ref()
-            if stream is not None:
+            if stream is not None and stream._error is None:
                 if kind == "rounds":
                     stream._flush_rows(*item)
                 else:
                     with stream._lock:
                         stream._emit(item[0])
                         stream._flush_sinks()
+        except Exception as e:       # re-raised by sync()
+            stream._error = e
         finally:
+            del stream
             q.task_done()
 
 
@@ -137,6 +143,7 @@ class MetricStream:
         self.rows: list = []
         self._lock = threading.Lock()
         self._queue: queue.Queue | None = None
+        self._error: BaseException | None = None    # first drainer failure
         # compiled flush programs, keyed by the step's metric-name tuple
         # (the chunk scans themselves come from rounds.py's weak caches)
         self._flushers: dict = {}
@@ -207,16 +214,24 @@ class MetricStream:
 
     def sync(self):
         """Block until every dispatched flush has reached the sinks (so
-        :attr:`rows` reflects all dispatched rounds)."""
+        :attr:`rows` reflects all dispatched rounds). Raises the drainer's
+        first exception, if it had one: rows after it were dropped."""
         jax.effects_barrier()
         if self._queue is not None:
             self._queue.join()
+        if self._error is not None:
+            raise RuntimeError(
+                f"metric stream {self.name!r}: the drainer failed, rows "
+                "after the failure were dropped") from self._error
 
     def close(self):
-        """Drain pending flushes and close every sink."""
-        self.sync()
-        for s in self.sinks:
-            s.close()
+        """Drain pending flushes and close every sink (closed even when the
+        drain raises)."""
+        try:
+            self.sync()
+        finally:
+            for s in self.sinks:
+                s.close()
 
     # -- device side --------------------------------------------------------
 

@@ -100,30 +100,17 @@ def jit_cost_summary(fn, *args) -> dict:
     """Compile ``fn(*args)`` and summarize its per-dispatch HLO cost.
 
     Returns ``{"xla": {...}, "flops": ..., "bytes": ..., "collectives": ...}``
-    — the XLA ``cost_analysis()`` dict (normalized across jax versions by
-    `hlo_cost.xla_cost_analysis`) alongside this package's own HLO-text
-    analysis. Every stage is guarded: a backend that can't lower or analyze
-    simply drops keys rather than raising, so the obs run-manifest probe
-    (launch/train.py) is safe on any platform."""
+    — the XLA ``cost_analysis()`` dict alongside this package's own
+    HLO-text analysis. A program that does not lower or compile raises: the
+    run it describes would fail the same way."""
     import jax
 
     from repro.roofline import hlo_cost
 
-    out: dict = {}
-    try:
-        compiled = jax.jit(fn).lower(*args).compile()
-    except Exception:
-        return out
-    xla = hlo_cost.xla_cost_analysis(compiled)
-    if xla:
-        out["xla"] = xla
-    try:
-        parsed = hlo_cost.analyze(compiled.as_text())
-        out.update({k: parsed[k] for k in ("flops", "bytes", "collectives")
-                    if k in parsed})
-    except Exception:
-        pass
-    return out
+    compiled = jax.jit(fn).lower(*args).compile()
+    parsed = hlo_cost.analyze(compiled.as_text())
+    return {"xla": hlo_cost.xla_cost_analysis(compiled),
+            **{k: parsed[k] for k in ("flops", "bytes", "collectives")}}
 
 
 def model_flops(cfg, num_tokens: int, param_count: int,
@@ -145,9 +132,7 @@ def active_params(cfg, tree) -> int:
         return count_params(tree)
     frac = cfg.experts_per_token / cfg.n_experts
     total = 0
-    flat = jax.tree.flatten_with_path(tree)[0] if hasattr(jax.tree, "flatten_with_path") \
-        else jax.tree_util.tree_flatten_with_path(tree)[0]
-    for path, leaf in flat:
+    for path, leaf in jax.tree.flatten_with_path(tree)[0]:
         pstr = "/".join(str(p) for p in path)
         n = int(np.prod(leaf.shape))
         if "moe" in pstr and any(w in pstr for w in ("wi", "wg", "wo")) \

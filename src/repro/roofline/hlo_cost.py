@@ -226,20 +226,9 @@ def _instr_bytes(ins: Instr, defs: Dict[str, str], comps, fusion_traffic) -> flo
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns a one-element list of per-device dicts, newer jax a
-    plain dict; keys like "flops"/"bytes accessed" have also drifted between
-    releases. Returns a (possibly empty) dict — callers must .get() keys and
-    fall back gracefully when one is absent.
-    """
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    """``compiled.cost_analysis()`` as a plain dict (keys such as "flops"
+    and "bytes accessed" vary by backend — callers .get() them)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def analyze(hlo: str) -> dict:

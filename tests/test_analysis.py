@@ -204,8 +204,10 @@ def test_dp_before_encode_catches_missing_and_spurious_noise():
                                                 int8=False)
 
 
-def test_collective_axes_catches_undeclared_axis():
-    from jax.experimental.shard_map import shard_map
+@pytest.mark.parametrize("check_vma", [True, False])
+def test_collective_axes_catches_undeclared_axis(check_vma):
+    """shard_map binds psum_invariant under check_vma, plain psum without:
+    the contract must see both."""
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_client_mesh
@@ -215,7 +217,8 @@ def test_collective_axes_catches_undeclared_axis():
     def summed(x):
         return jax.lax.psum(x, "data")
 
-    fn = shard_map(summed, mesh=mesh, in_specs=P("data"), out_specs=P())
+    fn = jax.shard_map(summed, mesh=mesh, in_specs=P("data"), out_specs=P(),
+                       check_vma=check_vma)
     closed = jax.make_jaxpr(fn)(jnp.zeros((jax.device_count(),)))
     assert contracts.check_collective_axes(closed.jaxpr, allowed=())
     assert not contracts.check_collective_axes(closed.jaxpr,

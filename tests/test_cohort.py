@@ -143,15 +143,6 @@ def test_ef_store_matches_dense_ef_with_frozen_nonparticipants():
     assert jnp.array_equal(dense1, store1.data)
 
 
-def test_ef_store_host_offload_same_interface():
-    a = ef_lib.ef_store_init(8, 3, host_offload=False)
-    b = ef_lib.ef_store_init(8, 3, host_offload=True)
-    ids = jnp.array([1, 6], jnp.int32)
-    rows = jnp.ones((2, 3), jnp.float32)
-    assert jnp.array_equal(a.scatter(ids, rows).data,
-                           b.scatter(ids, rows).data)
-
-
 # ---------------------------------------------------------------------------
 # virtual population == materialized dense container
 # ---------------------------------------------------------------------------

@@ -8,7 +8,7 @@ from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.data.synthetic import (classification_dataset, make_batch_iterator,
                                   token_dataset)
 from repro.roofline import hlo_cost
-from repro.roofline.analysis import HW, roofline_terms
+from repro.roofline.analysis import HW, jit_cost_summary, roofline_terms
 
 
 def test_checkpoint_roundtrip(tmp_path):
@@ -62,8 +62,7 @@ def test_hlo_cost_matches_xla_on_loop_free_module():
     got = hlo_cost.analyze(compiled.as_text())
     want_flops = 2 * 64 * 128 * 256 + 2 * 64 * 256 * 32
     assert abs(got["flops"] - want_flops) / want_flops < 1e-6
-    # xla_cost_analysis normalizes the list/dict return drift across jax
-    # versions; "bytes accessed" may be absent entirely on some backends.
+    # "bytes accessed" may be absent entirely on some backends
     xla_bytes = hlo_cost.xla_cost_analysis(compiled).get("bytes accessed")
     if xla_bytes:
         assert abs(got["bytes"] - xla_bytes) / xla_bytes < 0.2
@@ -82,6 +81,17 @@ def test_hlo_cost_scan_multiplier():
     got = hlo_cost.analyze(compiled.as_text())
     want = 12 * 2 * 64**3
     assert abs(got["flops"] - want) / want < 1e-6
+
+
+def test_jit_cost_summary_reports_and_raises():
+    """The run manifest's cost probe: a program that compiles is summarized,
+    one that does not raises instead of yielding an empty summary."""
+    x = jnp.ones((8, 16), jnp.float32)
+    got = jit_cost_summary(lambda a: jnp.tanh(a @ a.T), x)
+    assert set(got) == {"xla", "flops", "bytes", "collectives"}
+    assert got["flops"] >= 2 * 8 * 8 * 16
+    with pytest.raises(TypeError):
+        jit_cost_summary(lambda a: a @ a, x)     # (8,16) @ (8,16)
 
 
 def test_roofline_terms_bottleneck():

@@ -37,7 +37,8 @@ def test_expert_parallel_on_mesh_subprocess():
         from repro.configs.registry import ARCHS
         from repro.models import get_model
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         c0 = ARCHS["qwen3-moe-30b-a3b"].smoke()
         c1 = dataclasses.replace(c0, moe_sharding="expert_parallel")
         m = get_model(c0)
@@ -46,7 +47,7 @@ def test_expert_parallel_on_mesh_subprocess():
         batch = {"tokens": jax.random.randint(key, (4, 32), 0, c0.vocab_size),
                  "targets": jnp.ones((4, 32), jnp.int32)}
         l0 = float(m.loss_fn(params, batch, c0))
-        with mesh:
+        with jax.set_mesh(mesh):
             pspec = jax.tree.map(lambda s: jax.sharding.NamedSharding(mesh, s),
                                  m.param_specs(c1, "train"),
                                  is_leaf=lambda x: isinstance(x, P))

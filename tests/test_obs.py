@@ -278,3 +278,32 @@ def test_feature_dist_deprecation_warns_once():
     # the shim message carries the lint rule code so the runtime warning
     # and `python -m repro.analysis` point at the same rule
     assert str(dep[0].message).startswith("[FLT004]")
+
+
+class _FailingSink(MemorySink):
+    """Accepts the first row, then fails the way a full disk would."""
+
+    def __init__(self):
+        super().__init__()
+        self.closed = False
+
+    def emit(self, row):
+        if self.rows:
+            raise OSError("sink cannot write")
+        super().emit(row)
+
+    def close(self):
+        self.closed = True
+
+
+@pytest.mark.parametrize("transport", ["future", "callback"])
+def test_drainer_failure_raised_by_sync_and_close(transport):
+    sink = _FailingSink()
+    stream = MetricStream([sink], transport=transport)
+    _run_alg1(obs=stream)
+    with pytest.raises(RuntimeError, match="drainer failed") as err:
+        stream.sync()
+    assert isinstance(err.value.__cause__, OSError)
+    with pytest.raises(RuntimeError, match="drainer failed"):
+        stream.close()
+    assert sink.closed

@@ -1,0 +1,112 @@
+"""Main-path programs compiled for a described TPU v5e chip: no chip is
+attached, so nothing runs, but the TPU compiler refuses here what it would
+refuse on the chip (a Mosaic lowering it lacks, a block that breaks the
+(8, 128) tiling rule, a program that does not fit the chip's memory).
+
+The topology is described only inside the module-scoped fixture below:
+only one process at a time may load the TPU library, and the test workers
+all import this file, so describing it at import time would make the
+workers disagree on which tests exist. Every test here uses the fixture,
+and they all stay in this one file, so one worker loads the library.
+"""
+import dataclasses
+import importlib.util
+import os
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.comm.codecs import make_codec, tree_flat_dim
+from repro.configs import FLConfig
+from repro.core import optimizer, rounds
+from repro.launch import train
+from repro.models import get_model, mlp
+
+_spec = importlib.util.spec_from_file_location(
+    "chip_smoke", Path(__file__).resolve().parents[1] / "chip_smoke.py")
+chip_smoke = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(chip_smoke)
+
+V5E_HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep the cache out of it
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+            try:
+                desc = topologies.get_topology_desc(platform="tpu",
+                                                    topology_name="v5e:2x2")
+            except Exception as e:
+                pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+            yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def test_quantizer_compiles_at_lm_flat_dim(one_chip):
+    """The fused quantizer over a 4-layer qwen2.5-3b's flat gradient (the
+    train loop's ``--codec int8 --codec-impl pallas`` upload)."""
+    cfg = dataclasses.replace(chip_smoke.lm_config(), n_layers=4)
+    model = get_model(cfg)
+    p = tree_flat_dim(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), cfg)))
+    codec = make_codec("int8", impl="pallas")
+    x, key = _on(one_chip, (jax.ShapeDtypeStruct((p,), jnp.float32),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    compiled = jax.jit(codec.roundtrip).lower(x, key).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_quantizer_compiles_under_vmap_at_cohort_dim(one_chip):
+    """One quantizer call per client, vmapped over a 256-client cohort of
+    cohort_train_loop's default MLP (32 features, 16 hidden, 4 classes):
+    the vmapped scales block must keep to the tiling rule."""
+    p = tree_flat_dim(jax.eval_shape(
+        lambda: mlp.init(jax.random.PRNGKey(0), 32, 16, 4)))
+    codec = make_codec("int8", impl="pallas")
+    x, keys = _on(one_chip, (jax.ShapeDtypeStruct((256, p), jnp.float32),
+                             jax.ShapeDtypeStruct((256, 2), jnp.uint32)))
+    compiled = jax.jit(jax.vmap(codec.roundtrip)).lower(x, keys).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_depth_cut_lm_scan_step_fits_one_chip(one_chip):
+    """chip_smoke's LM trainer step (qwen2.5-3b at published widths, depth
+    cut, bf16 + remat, batch x seq as run there) leaves >= 2 GB of the
+    chip's 16 GB free by memory_analysis()."""
+    cfg = chip_smoke.lm_config()
+    model = get_model(cfg)
+    fl = FLConfig()
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), cfg))
+    state = _on(one_chip, jax.eval_shape(optimizer.ssca_init, params))
+    inputs = _on(one_chip, jax.eval_shape(
+        lambda: rounds.make_inputs(fl, 1, 1, jax.random.PRNGKey(0))))
+    tokens = jnp.zeros((200_000,), jnp.int32)
+    step = train.make_scanned_step(model, cfg, fl, tokens,
+                                   chip_smoke.LM_BATCH, chip_smoke.LM_SEQ)
+    m = rounds._scan_jit(step).lower(state, inputs).compile().memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total + 2e9 <= V5E_HBM_BYTES, total
