@@ -1,27 +1,34 @@
 """Cohort cells: the program's Algorithm 1 step over the participant-only
-engine (``core/algorithms.make_algorithm1_step(cohort=True)``) driven by
+engine (``core/algorithms.make_algorithm1_step(cohort=True)``) on the
+program's own population (``data.synthetic.VirtualFedData``), driven by
 ``core/rounds.run_rounds`` K rounds per dispatch with the cohort loop's
 eval probe between dispatches, as ``launch/train.cohort_train_loop`` runs
 it. The codec is the one the traffic names, in the implementation the
 program picks.
 
-Set-up drives the one state object from the seed through its first three
-rounds (one round per dispatch), reads what ``correct`` compares, then
-warms the K-round dispatch. The reference (bench/reference/mlp_fl.py)
-follows the same three rounds after the window.
+Set-up makes the weights from the seed and drives the one state object
+through the window's first dispatch (K rounds, the window's own compiled
+program and feed), which is also the warm-up. From it ``correct`` reads
+the K per-round losses, round 1's surrogate step (the stationarity
+residual the step reports) and the change of each leaf after K rounds. The
+reference (bench/reference/mlp_fl.py, on its own copy of the population)
+follows the same K rounds after the window.
 """
 from __future__ import annotations
 
 import gc
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
-from bench.lib import counts, data
+from bench.lib import counts
 from bench.lib.common import compare, seed_key
 from bench.reference import mlp_fl
+from bench.reference.population import Population
 
-PREFIX = 3
+DATA_KEY = 0x5EED        # the population is fixed: one compilation serves every seed
 
 
 def mlp_weights(key, features, hidden, classes):
@@ -30,51 +37,48 @@ def mlp_weights(key, features, hidden, classes):
             "w1": jax.random.normal(k1, (hidden, features)) / jnp.sqrt(features)}
 
 
-def leaf_norms(tree):
-    return {k: jnp.linalg.norm(v) for k, v in tree.items()}
-
-
-def floats(d):
-    return {k: float(v) for k, v in d.items()}
+def population_key():
+    return jax.random.fold_in(jax.random.PRNGKey(DATA_KEY), 0xDA7A)
 
 
 class Engine:
     unit = "rounds"
 
-    def __init__(self, config, traffic, seed, devices, log, warm=True):
+    def __init__(self, config, traffic, seed, devices, log):
         from repro.comm import error_feedback, make_codec
         from repro.configs import FLConfig
         from repro.core import algorithms, optimizer, rounds
+        from repro.data.synthetic import VirtualFedData
         from repro.models import mlp
 
-        self.tr, self.rounds = traffic, rounds
+        self.tr, self.cfg, self.rounds = traffic, config, rounds
         self.k = traffic["rounds_per_dispatch"]
         self.s, self.b = traffic["participation"], traffic["batch"]
         self.fl = FLConfig(batch_size=self.b, constrained=False,
                            **traffic["fl"])
-        self.pop = data.Population(traffic["population"],
-                                   config["num_features"],
-                                   config["num_classes"])
+        self.data = VirtualFedData(
+            population_key(), traffic["population"],
+            num_features=config["num_features"],
+            num_classes=config["num_classes"], noise=4.0)
         key = seed_key(seed)
-        self.wkey = jax.random.fold_in(key, 1)
         self.key_run0 = self.key_run = jax.random.fold_in(key, 2)
         dims = (config["num_features"], config["hidden"], config["num_classes"])
         self.params0 = jax.jit(mlp_weights, static_argnums=(1, 2, 3))(
-            self.wkey, *dims)
+            jax.random.fold_in(key, 1), *dims)
         self.p = counts.mlp_params(*dims)
         codec = make_codec(traffic["codec"])
         self.step = algorithms.make_algorithm1_step(
-            mlp.per_sample_loss, self.pop, self.fl, participation=self.s,
+            mlp.per_sample_loss, self.data, self.fl, participation=self.s,
             codec=codec, cohort=True)
         self.state = optimizer.ssca_init(self.params0)
         if codec is not None:
             self.state = error_feedback.CommCarry(
                 opt=self.state, ef=error_feedback.ef_store_init(
-                    self.pop.num_clients, self.p))
+                    self.data.num_clients, self.p))
         # the cohort loop's O(1) eval probe: the first clients' shards
-        eval_ids = jnp.arange(min(traffic["eval_clients"], self.pop.num_clients),
+        eval_ids = jnp.arange(min(traffic["eval_clients"], self.data.num_clients),
                               dtype=jnp.int32)
-        ez, ey, ec = self.pop.shards_for(eval_ids)
+        ez, ey, ec = self.data.shards_for(eval_ids)
         emask = (jnp.arange(ez.shape[1])[None, :] < ec[:, None]).astype(jnp.float32)
 
         def eval_fn(p, s):
@@ -83,34 +87,25 @@ class Engine:
 
         self.eval_fn = eval_fn
         self.t = 1
-        c_lin = 2 * self.fl.l2_lambda - 2 * self.fl.tau
-        self.prog = {"loss": []}
-        for i in range(PREFIX):
-            hist = self._dispatch(1)
-            self.prog["loss"].append(float(hist["round_loss_est"][0]))
-            if i == 0:
-                g = rounds.unwrap_comm(self.state).g
-                self.prog["grad"] = floats(leaf_norms(jax.tree.map(
-                    lambda a, w: a - c_lin * w, g, self.params0)))
+        self.dispatch()                 # the window's first dispatch
         params = rounds.unwrap_comm(self.state).params
-        self.prog["delta"] = floats(leaf_norms(jax.tree.map(
-            lambda a, w: a - w, params, self.params0)))
-        log(f"prefix losses {self.prog['loss']}")
-        if warm:
-            self.dispatch()                 # warm the K-round program
-
-    def _dispatch(self, size):
-        self.key_run, sub = jax.random.split(self.key_run)
-        res = self.rounds.run_rounds(self.step, self.state, self.fl, sub, size,
-                                     eval_fn=self.eval_fn, eval_every=self.k,
-                                     t_start=self.t)
-        self.state = res.final_state
-        self.t += size
-        self.last = res.history
-        return res.history
+        self.prog = {
+            "loss": [float(x) for x in self.last["round_loss_est"]],
+            "surrogate": 2 * self.fl.tau * float(self.last["round_stat_res"][0]),
+            "delta": {k: float(jnp.linalg.norm(params[k] - self.params0[k]))
+                      for k in params},
+            "params": {k: np.asarray(v) for k, v in params.items()}}
+        log(f"first dispatch: losses {self.prog['loss'][0]} .. "
+            f"{self.prog['loss'][-1]}")
 
     def dispatch(self) -> int:
-        self._dispatch(self.k)
+        self.key_run, sub = jax.random.split(self.key_run)
+        res = self.rounds.run_rounds(self.step, self.state, self.fl, sub,
+                                     self.k, eval_fn=self.eval_fn,
+                                     eval_every=self.k, t_start=self.t)
+        self.state = res.final_state
+        self.t += self.k
+        self.last = res.history
         return self.k
 
     def losses(self):
@@ -133,19 +128,19 @@ class Engine:
         return out
 
     def free(self):
-        self.state = self.step = None
+        self.state = self.step = self.data = self.eval_fn = self.last = None
         gc.collect()
 
     def round_keys(self):
-        """The keys of rounds 1..3 as the prefix dispatches derived them."""
-        keys, k = [], self.key_run0
-        for _ in range(PREFIX):
-            k, sub = jax.random.split(k)
-            keys.append(jax.random.split(jax.random.split(sub)[1], 1)[0])
-        return keys
+        """The keys of rounds 1..K as the first dispatch derived them
+        (``run_rounds`` splits its key once more, ``make_inputs`` into K)."""
+        sub = jax.random.split(self.key_run0)[1]
+        return list(jax.random.split(jax.random.split(sub)[1], self.k))
 
     def reference(self, precision: str = "f32", fault=None) -> dict:
-        return mlp_fl.cohort_rounds(self.pop, self.params0, self.round_keys(),
+        pop = Population(population_key(), self.tr["population"],
+                         self.cfg["num_features"], self.cfg["num_classes"])
+        return mlp_fl.cohort_rounds(pop, self.params0, self.round_keys(),
                                     self.tr["fl"], self.s, self.b,
                                     self.tr["codec"] == "int8", precision,
                                     fault)

@@ -1,36 +1,34 @@
-"""Matrix products at the highest float32 precision, or at float8 for the
-controls: ``precision="fp8"`` rounds each operand (and, backwards, the
-cotangent) to float8_e4m3fn with a per-tensor absmax scale and
-accumulates in float32, as fp8 training does."""
+"""Matrix products of the reference at full float32 precision, or one step
+lower for the controls: ``"high"`` is XLA's three-pass bfloat16 product
+(on the TPU; other backends may compute it in full float32), and
+``"bf16"`` rounds each operand (and, backwards, the cotangent) to bfloat16
+and accumulates in float32, as one bfloat16 pass does on any backend."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
 HI = jax.lax.Precision.HIGHEST
-E4M3_MAX = 448.0
 
 
-def _fp8(x):
-    amax = jnp.max(jnp.abs(x))
-    s = jnp.where(amax > 0, E4M3_MAX / amax, 1.0)
-    return (x * s).astype(jnp.float8_e4m3fn).astype(jnp.float32) / s
+def _bf16(x):
+    return x.astype(jnp.bfloat16).astype(jnp.float32)
 
 
 @jax.custom_vjp
-def _mm8(a, b):
-    return jnp.matmul(_fp8(a), _fp8(b), precision=HI)
+def _mm16(a, b):
+    return jnp.matmul(_bf16(a), _bf16(b), precision=HI)
 
 
-def _mm8_fwd(a, b):
-    return _mm8(a, b), (a, b)
+def _mm16_fwd(a, b):
+    return _mm16(a, b), (a, b)
 
 
-def _mm8_bwd(res, g):
+def _mm16_bwd(res, g):
     a, b = res
-    g8, a8, b8 = _fp8(g), _fp8(a), _fp8(b)
-    da = jnp.matmul(g8, jnp.swapaxes(b8, -1, -2), precision=HI)
-    db = jnp.matmul(jnp.swapaxes(a8, -1, -2), g8, precision=HI)
+    g16, a16, b16 = _bf16(g), _bf16(a), _bf16(b)
+    da = jnp.matmul(g16, jnp.swapaxes(b16, -1, -2), precision=HI)
+    db = jnp.matmul(jnp.swapaxes(a16, -1, -2), g16, precision=HI)
     while da.ndim > a.ndim:          # batch axes broadcast over a
         da = da.sum(0)
     while db.ndim > b.ndim:          # batch axes broadcast over b
@@ -38,13 +36,16 @@ def _mm8_bwd(res, g):
     return da, db
 
 
-_mm8.defvjp(_mm8_fwd, _mm8_bwd)
+_mm16.defvjp(_mm16_fwd, _mm16_bwd)
 
 
 def matmul(a, b, precision: str):
-    """a @ b in float32 ("f32") or from float8 operands ("fp8")."""
-    if precision == "fp8":
-        return _mm8(a, b)
+    """a @ b in float32 ("f32"), three bfloat16 passes ("high") or from
+    bfloat16 operands ("bf16")."""
+    if precision == "bf16":
+        return _mm16(a, b)
+    if precision == "high":
+        return jnp.matmul(a, b, precision=jax.lax.Precision.HIGH)
     if precision != "f32":
-        raise ValueError(f"precision {precision!r}: f32 or fp8")
+        raise ValueError(f"precision {precision!r}: f32, high or bf16")
     return jnp.matmul(a, b, precision=HI)

@@ -1,30 +1,22 @@
-"""Plain float32 references of the paper's sample-based Algorithm 1 over a
+"""Plain float32 reference of the paper's sample-based Algorithm 1 over a
 sampled cohort (optionally with int8 stochastic-rounding uploads and error
-feedback) and of the constrained feature-based Algorithm 4, both on the
-paper's two-layer swish network (section V, eq. (28)).
+feedback) on the paper's two-layer swish network (section V, eq. (28)).
 
 Written from the paper and from the protocol's stated random choices; it
-imports nothing of the program under test. Each round:
+imports nothing of the program under test. Each round: S clients are drawn
+without replacement by a keyed 6-round Feistel permutation of the
+population (murmur3 finaliser as the round function, cycle walking into
+[0, I)); client i draws B row indices in [0, N_i) from ``fold_in(key, i)``
+and uses B_i = min(B, N_i) of them; it uploads q_i = sum of its rows' loss
+gradients; with int8 it encodes q_i + r_i in 256-float chunks, each scaled
+by absmax/127 and rounded stochastically with uniform noise from
+``fold_in(fold_in(key, 0xC0DEC), i)``, and keeps the residual; the server
+sums w_i * q_i with w_i = (I/S) N_i / (B_i N) and applies eqs. (9), (10),
+(5).
 
-* cohort (Algorithm 1): S clients drawn without replacement by a keyed
-  6-round Feistel permutation of the population (murmur3 finaliser as the
-  round function, cycle walking into [0, I)); client i draws B row indices
-  in [0, N_i) from ``fold_in(key, i)`` and uses B_i = min(B, N_i) of them;
-  it uploads q_i = sum of its rows' loss gradients; with int8 it encodes
-  q_i + r_i in 256-float chunks, each scaled by absmax/127 and rounded
-  stochastically with uniform noise from ``fold_in(fold_in(key, 0xC0DEC),
-  i)``, and keeps the residual; the server sums w_i * q_i with
-  w_i = (I/S) N_i / (B_i N) and applies eqs. (9), (10), (5).
-* vertical (Algorithm 4): B rows drawn from ``key``; party i computes
-  h_i = z_i w1_i^T; the loss runs on sum_i h_i; gradients of the batch mean
-  update the constraint surrogate (eqs. (14)-(15)); Lemma 1's closed form
-  gives nu and the update.
-
-``precision="f32"`` computes at the highest matmul precision;
-``precision="fp8"`` (the control) takes each matrix product's operands at
-float8 (``bench/reference/lowp.py``): the program's float32 products run
-at the TPU's default precision, one bfloat16 pass, so the precision below
-the one it runs is float8.
+``precision="f32"`` computes every matrix product at full float32
+precision; the controls compute them one step lower (``"high"``,
+``"bf16"``: ``bench/reference/lowp.py``).
 """
 from __future__ import annotations
 
@@ -95,25 +87,26 @@ def int8_roundtrip(x, key, chunk: int = 256):
     return dec, x - dec
 
 
-def _flat(w0, w1):
-    return jnp.concatenate([w0.reshape(-1), w1.reshape(-1)])
-
-
 def cohort_rounds(pop, params0, round_keys, fl, participation, batch, int8,
                   precision="f32", fault=None):
     """Run len(round_keys) rounds of Algorithm 1 from ``params0`` ({'w0',
-    'w1'} float32). Returns the loss estimate of each round, the per-leaf
-    norms of round 1's aggregated gradient and of the change over all
-    rounds. ``fault`` plants a fault the check must catch: 'half_batch'
-    (each client's second half of rows left out, the first half counted
-    twice) or 'altered' (the first drawn client's upload negated)."""
+    'w1'} float32), round t at the paper's rho^t and gamma^t. Returns each
+    round's loss estimate; ``surrogate``, round 1's surrogate step
+    ||g^1 + 2 lambda w^0|| over the whole model (what the program's
+    stationarity residual reads at round 1, times 2 tau); the per-leaf
+    norms of round 1's aggregated gradient (``grad``) and of the change
+    over all rounds (``delta``); and the final weights. ``fault`` plants a
+    fault the check must catch: 'half_batch' (each client's second half of
+    rows left out, the first half counted twice) or 'altered' (the first
+    drawn client's upload negated)."""
     lam, tau = fl["l2_lambda"], fl["tau"]
     w0, w1 = params0["w0"], params0["w1"]
     n0 = w0.size
     g0, g1 = jnp.zeros_like(w0), jnp.zeros_like(w1)
-    residual = jnp.zeros((pop.num_clients, n0 + w1.size), jnp.float32)
+    residual = (jnp.zeros((pop.num_clients, n0 + w1.size), jnp.float32)
+                if int8 else None)
     num = pop.num_clients
-    losses, first = [], None
+    losses, first, surrogate = [], None, None
 
     def client(w0, w1, z, y, m):
         return jnp.sum(per_row_loss(w0, w1, z, y, precision) * m)
@@ -150,6 +143,8 @@ def cohort_rounds(pop, params0, round_keys, fl, participation, batch, int8,
         if t == 1:
             first = {"w0": float(jnp.linalg.norm(gh0)),
                      "w1": float(jnp.linalg.norm(gh1))}
+            surrogate = float(jnp.sqrt(jnp.sum((gh0 + 2 * lam * w0) ** 2)
+                                       + jnp.sum((gh1 + 2 * lam * w1) ** 2)))
         rho = 1.0 if t == 1 else min(fl["a1"] / t ** fl["alpha_rho"], 1.0)
         gamma = min(fl["a2"] / t ** fl["alpha_gamma"], 1.0)
         g0 = (1 - rho) * g0 + rho * (gh0 + (2 * lam - 2 * tau) * w0)
@@ -158,75 +153,6 @@ def cohort_rounds(pop, params0, round_keys, fl, participation, batch, int8,
         w1 = (1 - gamma) * w1 + gamma * (-g1 / (2 * tau))
     delta = {"w0": float(jnp.linalg.norm(w0 - params0["w0"])),
              "w1": float(jnp.linalg.norm(w1 - params0["w1"]))}
-    return {"loss": losses, "grad": first, "delta": delta}
-
-
-def vertical_rounds(features, labels, params0, round_keys, fl, batch,
-                    precision="f32", fault=None):
-    """Run len(round_keys) rounds of constrained Algorithm 4 (objective
-    ||w||^2, constraint mean loss <= cost_limit, Lemma 1) from ``params0``
-    ({'w0' (L, J), 'blocks' (I, J, P_i)}). ``features`` (N, P) is split
-    into I contiguous column blocks, one per party. ``fault`` plants a
-    fault the check must catch: 'half_batch' (the mean over the first half
-    of the rows), 'exchange' (no h exchange: the head sees party 0's h
-    alone, party i's block gradient its own h_i) or 'altered' (party 0's
-    block gradient negated)."""
-    tau, c, u = fl["tau"], fl["penalty_c"], fl["cost_limit"]
-    n, p = features.shape
-    parties, per = params0["blocks"].shape[0], params0["blocks"].shape[2]
-    assert parties * per == p, "the benchmark's feature split is exact"
-    blocks_data = features.reshape(n, parties, per).transpose(1, 0, 2)
-    w = dict(params0)
-    g = {k: jnp.zeros_like(v) for k, v in w.items()}
-    d = 0.0
-    losses, first = [], None
-
-    def head_loss(w0, h, yb):
-        lg = matmul(swish(h), w0.T, precision)
-        return -jnp.sum(yb * jax.nn.log_softmax(lg, -1)) / yb.shape[0]
-
-    def party_h(zb, blocks):
-        """(I, B, J): each party's h_i = z_i w1_i^T."""
-        return matmul(zb, jnp.swapaxes(blocks, -1, -2), precision)
-
-    def batch_loss(w0, blocks, zb, yb):
-        return head_loss(w0, party_h(zb, blocks).sum(0), yb)
-
-    def no_exchange(w0, blocks, zb, yb):
-        """(loss and head gradient from party 0's h alone, block i's
-        gradient through its own h_i alone)."""
-        val, gw0 = jax.value_and_grad(head_loss)(w0, party_h(zb, blocks)[0],
-                                                 yb)
-        gbl = jax.vmap(lambda bl, z: jax.grad(
-            lambda b_: head_loss(w0, party_h(z[None], b_[None])[0], yb))(bl))(
-            blocks, zb)
-        return val, (gw0, gbl)
-
-    vg = jax.jit(no_exchange if fault == "exchange"
-                 else jax.value_and_grad(batch_loss, argnums=(0, 1)))
-    for t, key in enumerate(round_keys, start=1):
-        idx = jax.random.randint(key, (batch,), 0, n)
-        if fault == "half_batch":
-            idx = idx[: batch // 2]
-        val, (gw0, gbl) = vg(w["w0"], w["blocks"], blocks_data[:, idx],
-                             labels[idx])
-        if fault == "altered":
-            gbl = gbl.at[0].multiply(-1.0)
-        ghat = {"w0": gw0.astype(jnp.float32), "blocks": gbl.astype(jnp.float32)}
-        val = float(val)
-        losses.append(val)
-        if t == 1:
-            first = {k: float(jnp.linalg.norm(v)) for k, v in ghat.items()}
-        rho = 1.0 if t == 1 else min(fl["a1"] / t ** fl["alpha_rho"], 1.0)
-        gamma = min(fl["a2"] / t ** fl["alpha_gamma"], 1.0)
-        dot = sum(float(jnp.vdot(ghat[k], w[k])) for k in w)
-        wsq = sum(float(jnp.vdot(w[k], w[k])) for k in w)
-        g = {k: (1 - rho) * g[k] + rho * (ghat[k] - 2 * tau * w[k]) for k in w}
-        d = (1 - rho) * d + rho * (val - u - dot + tau * wsq)
-        b = sum(float(jnp.vdot(g[k], g[k])) for k in g)
-        disc = b - 4 * tau * d
-        nu = min(max((np.sqrt(b / disc) - 1) / tau, 0.0), c) if disc > 0 else c
-        w = {k: (1 - gamma) * w[k] + gamma * (-(nu * g[k]) / (2 * (1 + nu * tau)))
-             for k in w}
-    delta = {k: float(jnp.linalg.norm(w[k] - params0[k])) for k in w}
-    return {"loss": losses, "grad": first, "delta": delta}
+    return {"loss": losses, "surrogate": surrogate, "grad": first,
+            "delta": delta, "params": {"w0": np.asarray(w0),
+                                       "w1": np.asarray(w1)}}

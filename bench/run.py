@@ -9,8 +9,9 @@ is ``bench/configs/<config>.json``, its traffic ``bench/traffic/<traffic>
 per-layer metric is read by ``bench/metrics/<metric>.py``. Nothing here
 lists them.
 
-A run: set-up (weights and data from the seed on the device, the first
-steps that ``correct`` compares, warm-up of the window's program), then
+A run: set-up (weights and data from the seed on the device, the
+window's first dispatch, which ``correct`` compares and which warms the
+window's program), then
 ``--seconds`` of dispatches with the profiler off (``--trace 0``), or a few
 traced dispatches reduced to the per-layer metrics (``--trace 1``); then
 the peak device memory, then the plain reference, and last the result line.
@@ -128,9 +129,14 @@ def run_once(name, config, traffic, e2e, per_layer, seed, seconds, trace,
              devices, peaks):
     """One run of a cell on ``devices`` after the device gate: set-up, the
     window, the peak memory, the reference. Returns (result, checks)."""
+    import jax
     from bench.lib import common
     from bench.lib.trace import breakdown
 
+    # the precision the configuration states for its float32 products; on
+    # the TPU the default would run them as one bfloat16 pass
+    jax.config.update("jax_default_matmul_precision",
+                      config["matmul_precision"])
     counter = common.CompileCounter()
     engine = importlib.import_module(f"bench.engines.{traffic['engine']}")
     eng = engine.Engine(config, traffic, seed, devices, log)
@@ -162,7 +168,9 @@ def run_once(name, config, traffic, e2e, per_layer, seed, seconds, trace,
             result["metrics"][m["name"]] = {"value": readings[m["name"]],
                                             "unit": m["unit"]}
     eng.free()
+    t_ref = time.perf_counter()
     checks = eng.check() + checks
+    log(f"reference {time.perf_counter() - t_ref} s")
     checks.append(("failed_rounds", failed, 0, "non-finite losses"))
     ok = all(lim is not None and v <= lim for _, v, lim, _ in checks)
     result.update(correct=ok, attempted=attempted, failed=failed,

@@ -2,11 +2,9 @@
 skips the harness's look for a chip, drives the rest of a run
 (``run.run_once``: set-up, window, peak memory, reference) at a size a CPU
 holds, with the program broken underneath, and sees ``correct`` come out
-false; the unbroken run comes out true. The vertical cell needs four
-devices:
+false; the unbroken run comes out true:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \\
-        python -m pytest bench/tests -q
+    JAX_PLATFORMS=cpu python -m pytest bench/tests -q
 """
 import json
 
@@ -20,49 +18,15 @@ from bench.lib.common import BENCH, peaks_for
 jax.config.update("jax_enable_compilation_cache", False)
 
 
-# the LM and vertical engines have no cell yet (not proven on the chip):
-# their traffic, with the cohort cells' limits, lives here
-FL = {"a1": 0.9, "a2": 0.5, "alpha_rho": 0.1, "alpha_gamma": 0.6, "tau": 0.2,
-      "l2_lambda": 1e-05, "cost_limit": 1.2, "penalty_c": 1e4}
-LIMITS = {"loss_gap": 4e-05, "grad_gap": 0.001, "delta_gap": 0.001}
-UNCELLED = {
-    "qwen2.5-3b.silo-dense": ("qwen2.5-3b", {
-        "engine": "lm_silo", "batch": 4, "seq": 512, "rounds_per_dispatch": 10,
-        "corpus_tokens": 262144, "codec": None,
-        "fl": dict(FL, cost_limit=3.0), "limits": LIMITS}),
-    "mnist-mlp.vertical-c4": ("mnist-mlp", {
-        "engine": "vertical", "parties": 4, "batch": 64,
-        "rounds_per_dispatch": 20, "codec": None, "fl": FL,
-        "limits": LIMITS}),
-}
-
-
 def tiny(cell):
     spec = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    if cell in UNCELLED:
-        config, tr = UNCELLED[cell]
-        w, tr = {"config": config}, dict(tr)
-    else:
-        w = {x["name"]: x for x in spec["workloads"]}[cell]
-        tr = json.loads((BENCH / "traffic" / f"{w['traffic']}.json").read_text())
+    w = {x["name"]: x for x in spec["workloads"]}[cell]
+    tr = json.loads((BENCH / "traffic" / f"{w['traffic']}.json").read_text())
     cfg = json.loads((BENCH / "configs" / f"{w['config']}.json").read_text())
-    if tr["engine"] == "lm_silo":
-        cfg.update(hidden_size=128, intermediate_size=256,
-                   num_attention_heads=4, num_key_value_heads=2,
-                   num_hidden_layers=2, vocab_size=256, torch_dtype="float32")
-        tr.update(batch=4, seq=16, rounds_per_dispatch=2, corpus_tokens=2048)
-    elif tr["engine"] == "cohort":
-        cfg.update(num_features=16, hidden=8, num_classes=4)
-        tr.update(population=40, participation=8, batch=4,
-                  rounds_per_dispatch=2, eval_clients=8)
-    else:
-        cfg.update(num_features=16, hidden=8, num_classes=4, n_train=128)
-        tr.update(batch=8, rounds_per_dispatch=2)
-    chips = tr.get("parties", 1)
-    if jax.device_count() < chips:
-        pytest.skip(f"needs {chips} devices: set XLA_FLAGS="
-                    "--xla_force_host_platform_device_count=4")
-    return cell, cfg, tr, jax.devices()[:chips]
+    cfg.update(num_features=16, hidden=8, num_classes=4)
+    tr.update(population=40, participation=8, batch=4, rounds_per_dispatch=3,
+              eval_clients=8)
+    return cell, cfg, tr, jax.devices()[:w["chips"]]
 
 
 def drive(cell):
@@ -74,8 +38,8 @@ def drive(cell):
     return result
 
 
-CELLS = ["qwen2.5-3b.silo-dense", "mnist-mlp.cohort-int8-ef",
-         "mnist-mlp.cohort-dense", "mnist-mlp.vertical-c4"]
+CELLS = [w["name"] for w in json.loads(
+    (BENCH.parent / "BENCHMARK.json").read_text())["workloads"]]
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -88,15 +52,12 @@ def _state_unchanged(monkeypatch, cell):
     from repro.core import optimizer
     monkeypatch.setattr(optimizer, "ssca_step",
                         lambda state, *a, **k: state._replace(t=state.t + 1))
-    monkeypatch.setattr(optimizer, "ssca_constrained_step",
-                        lambda state, *a, **k: state._replace(t=state.t + 1))
 
 
 def _rows_fault(monkeypatch, cell, how):
     """Break the per-row losses where the program produces them: 'half'
     leaves out the second half of each batch and doubles the rest (the
     mean over what is left); 'altered' doubles one row's loss."""
-    from repro.data import synthetic
     from repro.models import mlp
 
     def bend(per_row):
@@ -105,37 +66,13 @@ def _rows_fault(monkeypatch, cell, how):
             return per_row * 2.0 * (jnp.arange(b) < b // 2)
         return per_row.at[0].multiply(2.0)
 
-    if cell.startswith("qwen"):
-        sample = synthetic.sample_window
-
-        def window(tokens, key, batch, seq):
-            d = sample(tokens, key, batch, seq)
-            if how == "half":
-                return {k: v[: batch // 2] for k, v in d.items()}
-            return {"tokens": d["tokens"],
-                    "targets": d["targets"].at[:, -1].add(1)}
-
-        monkeypatch.setattr(synthetic, "sample_window", window)
-        return
-    psl, pslh = mlp.per_sample_loss, mlp.per_sample_loss_from_h
+    psl = mlp.per_sample_loss
     monkeypatch.setattr(mlp, "per_sample_loss",
                         lambda p, z, y: bend(psl(p, z, y)))
-    monkeypatch.setattr(mlp, "per_sample_loss_from_h",
-                        lambda w0, h, y: bend(pslh(w0, h, y)))
 
 
-def _no_exchange(monkeypatch, cell):
-    """The h all-gather between chips left out: each chip tiles its own."""
-    def local_only(x, axis_name, *, axis=0, tiled=False, **kw):
-        n = jax.lax.axis_size(axis_name)
-        return jnp.concatenate([x] * n, axis=axis)
-    monkeypatch.setattr(jax.lax, "all_gather", local_only)
-
-
-FAULTS = [(c, "state_unchanged") for c in CELLS] + \
-         [(c, "half_batch") for c in CELLS] + \
-         [(c, "altered") for c in CELLS] + \
-         [("mnist-mlp.vertical-c4", "no_exchange")]
+FAULTS = [(c, f) for c in CELLS
+          for f in ("state_unchanged", "half_batch", "altered")]
 
 
 @pytest.mark.parametrize("cell,fault", FAULTS)
@@ -145,25 +82,41 @@ def test_fault_makes_run_incorrect(monkeypatch, cell, fault):
         _state_unchanged(monkeypatch, cell)
     elif fault == "half_batch":
         _rows_fault(monkeypatch, cell, "half")
-    elif fault == "altered":
-        _rows_fault(monkeypatch, cell, "altered")
     else:
-        _no_exchange(monkeypatch, cell)
+        _rows_fault(monkeypatch, cell, "altered")
     r = drive(cell)
     assert not r["correct"], r["checks"]
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_control_is_not_correct(cell):
-    """The reference one precision lower (float8 products), put in the
+    """The reference one precision lower (bfloat16 operands), put in the
     program's place, fails at least one of the cell's limits."""
     import importlib
 
     from bench.lib.common import compare
     name, cfg, tr, devices = tiny(cell)
     engine = importlib.import_module(f"bench.engines.{tr['engine']}")
-    eng = engine.Engine(cfg, tr, 99, devices, lambda m: None, warm=False)
+    eng = engine.Engine(cfg, tr, 99, devices, lambda m: None)
     eng.free()
     ref = eng.reference()
-    checks = compare(eng.reference("fp8"), ref, tr["limits"])
+    checks = compare(eng.reference("bf16"), ref, tr["limits"])
     assert any(v > lim for _, v, lim, _ in checks), checks
+
+
+def test_reference_population_makes_the_program_rows():
+    """The reference's own population copy makes, bit for bit, the rows,
+    shard sizes and total of the program's VirtualFedData."""
+    from bench.engines.cohort import population_key
+    from bench.reference.population import Population
+    from repro.data.synthetic import VirtualFedData
+
+    prog = VirtualFedData(population_key(), 40, num_features=16,
+                          num_classes=4, noise=4.0)
+    ref = Population(population_key(), 40, 16, 4)
+    ids = jnp.array([0, 7, 39], jnp.int32)
+    idx = jnp.array([[0, 3, 31], [1, 1, 2], [5, 8, 30]], jnp.int32)
+    assert prog.total == ref.total
+    assert (prog.counts_for(ids) == ref.counts_for(ids)).all()
+    for a, b in zip(prog.batch_rows(ids, idx), ref.batch_rows(ids, idx)):
+        assert (a == b).all()

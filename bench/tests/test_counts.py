@@ -1,11 +1,11 @@
 """Hand counts for the FLOP and byte functions. Run: python -m pytest bench/tests"""
-import json
-from pathlib import Path
-
 from bench.lib import counts
 
-CFG = json.loads((Path(__file__).resolve().parents[1] / "configs"
-                  / "qwen2.5-3b.json").read_text())
+# Qwen2.5-3B's published widths (hf:Qwen/Qwen2.5-3B config.json), depth cut
+# from 36 to 10 layers
+CFG = {"hidden_size": 2048, "intermediate_size": 11008,
+       "num_attention_heads": 16, "num_key_value_heads": 2,
+       "num_hidden_layers": 10, "vocab_size": 151936}
 
 
 def test_qwen_10_layer_cut_has_1_08_b_params():
