@@ -59,6 +59,7 @@ from repro.core import privacy as privacy_lib
 from repro.core import rounds as rounds_lib
 from repro.core.fed import FeatureFedData, SampleFedData
 from repro.core.rounds import RunResult  # re-exported (public API since seed)
+from repro.obs import trace as obs_trace
 
 
 def _run(step_fn, state, key, num_rounds: int, eval_fn: Optional[Callable],
@@ -239,17 +240,19 @@ def make_algorithm1_step(per_sample_loss, data: SampleFedData, fl,
                 topology=topology, dp=dp)
         new = optimizer.ssca_step(state, grad_est, fl,
                                   rho_t=inp.rho, gamma_t=inp.gamma)
-        metrics = {"loss_est": val_est,
-                   "stat_res": _stat_res(new.params, state.params, inp.gamma),
-                   "upload_bytes": _sample_upload_bytes(
-                       up, grad_est, data, participation),
-                   "axis_bytes": _axis_bytes_metric(topology, grad_est)}
-        if codec is not None:
-            metrics["ef_norm"] = (_cohort_ef_norm(up) if cohort
-                                  else _ef_norm(up["ef"]))
-        if dp is not None:
-            metrics.update(_dp_metrics(eps_fn, up["dp"],
-                                       up.get("participants"), inp))
+        with obs_trace.phase("round-metrics"):
+            metrics = {"loss_est": val_est,
+                       "stat_res": _stat_res(new.params, state.params,
+                                             inp.gamma),
+                       "upload_bytes": _sample_upload_bytes(
+                           up, grad_est, data, participation),
+                       "axis_bytes": _axis_bytes_metric(topology, grad_est)}
+            if codec is not None:
+                metrics["ef_norm"] = (_cohort_ef_norm(up) if cohort
+                                      else _ef_norm(up["ef"]))
+            if dp is not None:
+                metrics.update(_dp_metrics(eps_fn, up["dp"],
+                                           up.get("participants"), inp))
         return new, up["ef"], metrics
 
     return with_comm_carry(codec, body)
@@ -298,19 +301,22 @@ def make_algorithm2_step(per_sample_loss, data: SampleFedData, fl,
                 ef=ef, topology=topology, dp=dp)
         new = optimizer.ssca_constrained_step(state, grad_est, val_est, fl,
                                               rho_t=inp.rho, gamma_t=inp.gamma)
-        metrics = {"loss_est": val_est, "nu": new.nu, "slack": new.slack,
-                   "stat_res": _stat_res(new.params, state.params, inp.gamma),
-                   "cons_viol": jnp.maximum(val_est - fl.cost_limit, 0.0),
-                   "upload_bytes": _sample_upload_bytes(
-                       up, grad_est, data, participation, with_value=True),
-                   "axis_bytes": _axis_bytes_metric(topology, grad_est,
-                                                    with_value=True)}
-        if codec is not None:
-            metrics["ef_norm"] = (_cohort_ef_norm(up) if cohort
-                                  else _ef_norm(up["ef"]))
-        if dp is not None:
-            metrics.update(_dp_metrics(eps_fn, up["dp"],
-                                       up.get("participants"), inp))
+        with obs_trace.phase("round-metrics"):
+            metrics = {"loss_est": val_est, "nu": new.nu, "slack": new.slack,
+                       "stat_res": _stat_res(new.params, state.params,
+                                             inp.gamma),
+                       "cons_viol": jnp.maximum(val_est - fl.cost_limit, 0.0),
+                       "upload_bytes": _sample_upload_bytes(
+                           up, grad_est, data, participation,
+                           with_value=True),
+                       "axis_bytes": _axis_bytes_metric(topology, grad_est,
+                                                        with_value=True)}
+            if codec is not None:
+                metrics["ef_norm"] = (_cohort_ef_norm(up) if cohort
+                                      else _ef_norm(up["ef"]))
+            if dp is not None:
+                metrics.update(_dp_metrics(eps_fn, up["dp"],
+                                           up.get("participants"), inp))
         return new, up["ef"], metrics
 
     return with_comm_carry(codec, body)
@@ -383,31 +389,34 @@ def algorithm2_general(obj_loss, cons_loss, params0, data: SampleFedData, fl,
                                           dp=dp)
         new = optimizer.ssca_general_constrained_step(
             state, og, cg, cv, fl, rho_t=inp.rho, gamma_t=inp.gamma)
-        bts = (_sample_upload_bytes(uo, og, data, participation)
-               + _sample_upload_bytes(uc, cg, data, participation,
-                                      with_value=True))
-        metrics = {"cons_est": cv, "nu": new.nu, "slack": new.slack,
-                   "stat_res": _stat_res(new.params, state.params, inp.gamma),
-                   "cons_viol": jnp.maximum(cv - fl.cost_limit, 0.0),
-                   "upload_bytes": bts,
-                   "axis_bytes": (_axis_bytes_metric(topology, og)
-                                  + _axis_bytes_metric(topology, cg,
-                                                       with_value=True))}
         new_ef = {"obj": uo["ef"], "cons": uc["ef"]}
-        if codec is not None:
-            metrics["ef_norm"] = (
-                _cohort_ef_norm({"cohort": uo["cohort"], "ef": new_ef})
-                if cohort else _ef_norm(new_ef))
-        if dp is not None:
-            pm = uo.get("participants")
-            mo = _dp_metrics(eps_fn, uo["dp"], pm, inp)
-            mc = _dp_metrics(eps_fn, uc["dp"], pm, inp)
-            metrics.update({
-                "dp_epsilon": mo["dp_epsilon"],
-                "dp_clip_frac": 0.5 * (mo["dp_clip_frac"]
-                                       + mc["dp_clip_frac"]),
-                "dp_noise_norm": jnp.sqrt(jnp.square(mo["dp_noise_norm"])
-                                          + jnp.square(mc["dp_noise_norm"]))})
+        with obs_trace.phase("round-metrics"):
+            bts = (_sample_upload_bytes(uo, og, data, participation)
+                   + _sample_upload_bytes(uc, cg, data, participation,
+                                          with_value=True))
+            metrics = {"cons_est": cv, "nu": new.nu, "slack": new.slack,
+                       "stat_res": _stat_res(new.params, state.params,
+                                             inp.gamma),
+                       "cons_viol": jnp.maximum(cv - fl.cost_limit, 0.0),
+                       "upload_bytes": bts,
+                       "axis_bytes": (_axis_bytes_metric(topology, og)
+                                      + _axis_bytes_metric(topology, cg,
+                                                           with_value=True))}
+            if codec is not None:
+                metrics["ef_norm"] = (
+                    _cohort_ef_norm({"cohort": uo["cohort"], "ef": new_ef})
+                    if cohort else _ef_norm(new_ef))
+            if dp is not None:
+                pm = uo.get("participants")
+                mo = _dp_metrics(eps_fn, uo["dp"], pm, inp)
+                mc = _dp_metrics(eps_fn, uc["dp"], pm, inp)
+                metrics.update({
+                    "dp_epsilon": mo["dp_epsilon"],
+                    "dp_clip_frac": 0.5 * (mo["dp_clip_frac"]
+                                           + mc["dp_clip_frac"]),
+                    "dp_noise_norm": jnp.sqrt(
+                        jnp.square(mo["dp_noise_norm"])
+                        + jnp.square(mc["dp_noise_norm"]))})
         return new, new_ef, metrics
 
     step = with_comm_carry(codec, body)
@@ -487,15 +496,17 @@ def _make_feature_step(head_loss_from_h, client_h, data, fl, codec,
             state.params, data, inp.key, fl.batch_size, head_loss_from_h,
             client_h, codec=codec, ef=ef, topology=topology, dp=dp)
         new, metrics = update_fn(state, grad_est, val_est, inp)
-        metrics["stat_res"] = _stat_res(new.params, state.params, inp.gamma)
-        metrics["upload_bytes"] = _feature_upload_bytes(up, grad_est, data,
-                                                       fl.batch_size)
-        metrics["axis_bytes"] = _feature_axis_bytes(topology, up)
-        if codec is not None:
-            metrics["ef_norm"] = _ef_norm(up["ef"])
-        if dp is not None:
-            metrics.update(_dp_feature_metrics(eps_fn, up["dp"],
-                                               data.num_clients, inp))
+        with obs_trace.phase("round-metrics"):
+            metrics["stat_res"] = _stat_res(new.params, state.params,
+                                            inp.gamma)
+            metrics["upload_bytes"] = _feature_upload_bytes(
+                up, grad_est, data, fl.batch_size)
+            metrics["axis_bytes"] = _feature_axis_bytes(topology, up)
+            if codec is not None:
+                metrics["ef_norm"] = _ef_norm(up["ef"])
+            if dp is not None:
+                metrics.update(_dp_feature_metrics(eps_fn, up["dp"],
+                                                   data.num_clients, inp))
         return new, up["ef"], metrics
 
     return with_comm_carry(codec, body)

@@ -40,6 +40,7 @@ from repro.core.algorithms import (RunResult, _check_cohort,
                                    _wrap_codec_state)
 from repro.core.fed import FeatureFedData, SampleFedData
 from repro.core.tree import tree_axpy, tree_l2sq, tree_zeros_like
+from repro.obs import trace as obs_trace
 
 
 class SGDConfig(NamedTuple):
@@ -136,12 +137,16 @@ def sample_sgd(per_sample_loss, params0, data: SampleFedData, cfg: SGDConfig,
             w = ((num_clients / participation)
                  * counts_s.astype(jnp.float32) / data.total)
             ckeys = fed.client_keys(ck, ids) if codec is not None else None
-            ef_rows = (ef.gather(ids)
-                       if codec is not None and ef is not None else None)
+            ef_rows = None
+            if codec is not None and ef is not None:
+                with obs_trace.phase("ef-gather"):
+                    ef_rows = ef.gather(ids)
             s = topo.weighted_sum(client_fn, (feats, labs, counts_s, keys), w,
                                   codec=codec, ef=ef_rows, codec_keys=ckeys)
-            new_ef = (ef.scatter(ids, s.ef)
-                      if codec is not None and ef is not None else s.ef)
+            new_ef = s.ef
+            if ef_rows is not None:
+                with obs_trace.phase("ef-scatter"):
+                    new_ef = ef.scatter(ids, s.ef)
         else:
             keys = fed.client_keys(inp.key, jnp.arange(num_clients))
             w = data.counts.astype(jnp.float32) / jnp.sum(data.counts)

@@ -541,7 +541,8 @@ def cohort_round(per_sample_loss: Callable, params, data, key,
                     f"a dense residual array — got {type(ef).__name__}")
             _check_ef_shape("cohort_round", "q_grad", ef.data,
                             (num_clients, dim))
-            ef_rows = ef.gather(ids)                                  # (S, P)
+            with obs_trace.phase("ef-gather"):
+                ef_rows = ef.gather(ids)                              # (S, P)
         if codec_key is None:
             codec_key = jax.random.fold_in(key, 0xC0DEC)
         ckeys = client_keys(codec_key, ids)
@@ -560,8 +561,10 @@ def cohort_round(per_sample_loss: Callable, params, data, key,
     s = topo.weighted_sum(client, (zb, yb, bmask), w, codec=codec,
                           ef=ef_rows, codec_keys=ckeys, active=active,
                           dp=dp, dp_keys=dkeys, dp_scale=dscale)
-    new_ef = ef.scatter(ids, s.ef) if (codec is not None
-                                       and ef is not None) else s.ef
+    new_ef = s.ef
+    if codec is not None and ef is not None:
+        with obs_trace.phase("ef-scatter"):
+            new_ef = ef.scatter(ids, s.ef)
     uploads = {"q_grad_sums": s.uploads,
                "q_value_sums": s.values if with_value else None,
                "cohort": ids, "encoded": s.encoded, "ef": new_ef,

@@ -242,28 +242,36 @@ def run_rounds(step_fn: Callable, state, fl, key, rounds: int,
     hist: dict = {"round": []}
     per_round: list = []
     t0 = t_start
+    # host spans (obs/trace.DRIVER_SPANS) put each device idle gap down to
+    # the driver's work in it: eager schedule ops, the K-round enqueue, the
+    # eval hook's host read, the history assembly
     for size in sizes:
-        key, sub = jax.random.split(key)
-        inputs = make_inputs(fl, t0, size, sub)
-        if obs is not None:
-            state, ms = obs.run(step_fn, state, inputs, driver=driver)
-        else:
-            state, ms = engine(step_fn, state, inputs)
+        with obs_trace.host_span("rounds/inputs"):
+            key, sub = jax.random.split(key)
+            inputs = make_inputs(fl, t0, size, sub)
+        with obs_trace.host_span("rounds/launch", rounds=size, t=t0):
+            if obs is not None:
+                state, ms = obs.run(step_fn, state, inputs, driver=driver)
+            else:
+                state, ms = engine(step_fn, state, inputs)
         t0 += size
         per_round.append(ms)
         if eval_fn is not None:
-            metrics = eval_fn(extract_params(state), state)
-            _check_eval_keys(metrics, per_round[0])
-            for k, v in metrics.items():
-                hist.setdefault(k, []).append(v)
-            hist["round"].append(t0 - t_start)
-            if obs is not None:
-                _emit_eval(obs, metrics, t0 - 1)
-    history = {k: jnp.asarray(v) for k, v in hist.items()}
-    if per_round and per_round[0]:
-        for k in per_round[0]:
-            history["round_" + k] = jnp.concatenate([m[k] for m in per_round])
-        history["round_t"] = jnp.arange(t_start, t0)
+            with obs_trace.host_span("rounds/eval"):
+                metrics = eval_fn(extract_params(state), state)
+                _check_eval_keys(metrics, per_round[0])
+                for k, v in metrics.items():
+                    hist.setdefault(k, []).append(v)
+                hist["round"].append(t0 - t_start)
+                if obs is not None:
+                    _emit_eval(obs, metrics, t0 - 1)
+    with obs_trace.host_span("rounds/history"):
+        history = {k: jnp.asarray(v) for k, v in hist.items()}
+        if per_round and per_round[0]:
+            for k in per_round[0]:
+                history["round_" + k] = jnp.concatenate(
+                    [m[k] for m in per_round])
+            history["round_t"] = jnp.arange(t_start, t0)
     return RunResult(extract_params(state), history, state)
 
 

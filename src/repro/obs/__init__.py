@@ -8,10 +8,14 @@ module scope, so core modules are free to use `repro.obs.trace` phases):
   chunked, ordered `io_callback`, without unrolling the scan or changing
   the trajectory (bitwise — pinned in tests/test_obs.py).
 * :mod:`repro.obs.trace` — `phase` (in-jit `jax.named_scope` annotations
-  for the protocol phases: round → client-compute → codec-encode →
-  collective → surrogate-solve), `HostSpans` (host wall-clock spans at
-  dispatch boundaries via `jax.profiler.TraceAnnotation`), and
-  `profile(dir)` (an xprof/perfetto trace of the whole run).
+  for the protocol phases listed in `trace.PHASES`: round → cohort-select →
+  batch-select → client-compute → dp-privatize → codec-encode →
+  ef-gather/ef-scatter → aggregate → collective → head-compute →
+  surrogate-solve → round-metrics), `host_span` (a
+  `jax.profiler.TraceAnnotation`; the round driver's ``rounds/inputs``,
+  ``rounds/launch``, ``rounds/eval`` and ``rounds/history``), `HostSpans`
+  (host wall-clock span rows at dispatch boundaries), and `profile(dir)`
+  (an xprof/perfetto trace of the whole run).
 * :mod:`repro.obs.sinks` — pluggable row consumers (JSONL/CSV/stdout/
   memory), the run manifest (config, mesh, codec, topology, git sha,
   per-dispatch HLO cost), and `bench_json` (the BENCH_*.json emitter the

@@ -4,20 +4,28 @@ Two clocks, one vocabulary:
 
 * **In-jit phases** (`phase`): `jax.named_scope` annotations compiled into
   the HLO metadata, so an xprof/perfetto dump attributes device time to
-  protocol phases — ``round → client-compute → codec-encode → collective →
-  surrogate-solve``. Scopes are free at runtime (they only label ops at
-  trace time) and therefore safe on the hot path; they are applied inside
-  `core/topology.py`, `core/optimizer.py`, `core/fed.py`, and the round
-  drivers unconditionally.
-* **Host spans** (`HostSpans`): wall-clock timing at dispatch boundaries —
-  the scan dispatch itself, eval hooks, checkpoint writes — paired with
-  `jax.profiler.TraceAnnotation` so the same names appear on the profiler
-  timeline. Spans are plain rows (``kind="span"``) emitted through the
-  sink API, so a JSONL log interleaves rounds, evals, and spans in order.
+  protocol phases — ``round → cohort-select → batch-select →
+  client-compute → dp-privatize → codec-encode → ef-gather/ef-scatter →
+  aggregate → collective → head-compute → surrogate-solve →
+  round-metrics`` (`PHASES`). Scopes are free at runtime (they only label
+  ops at trace time) and therefore safe on the hot path; they are applied
+  inside `core/topology.py`, `core/optimizer.py`, `core/fed.py`,
+  `core/algorithms.py`, `core/baselines.py` and the round drivers
+  unconditionally.
+* **Host spans** (`host_span`): `jax.profiler.TraceAnnotation`s, events of
+  the profiler's host plane on the same clock as the device planes, so an
+  idle gap of the device can be put down to what the host was doing.
+  `core/rounds.run_rounds` runs each chunk under ``rounds/inputs``,
+  ``rounds/launch`` and ``rounds/eval`` and the history assembly under
+  ``rounds/history`` (`DRIVER_SPANS`); with the profiler off each costs one
+  annotation object. `HostSpans` adds wall-clock timing at dispatch
+  boundaries — the scan dispatch itself, eval hooks, checkpoint writes — as
+  plain rows (``kind="span"``) emitted through the sink API, so a JSONL log
+  interleaves rounds, evals, and spans in order.
 
 `profile(logdir)` wraps a whole run in `jax.profiler.start_trace` /
 `stop_trace`; the resulting directory opens in xprof/perfetto and contains
-the named scopes above (exercised by the CI obs-smoke job).
+the named scopes and host spans above (exercised by the CI obs-smoke job).
 """
 from __future__ import annotations
 
@@ -28,10 +36,17 @@ import time
 
 import jax
 
-# the canonical phase names, in protocol order (DESIGN.md §13); free-form
-# names are allowed everywhere, this is the shared vocabulary
-PHASES = ("round", "client-compute", "codec-encode", "collective",
-          "aggregate", "head-compute", "batch-select", "surrogate-solve")
+# the phase names the program uses, in protocol order (DESIGN.md §13);
+# tests/test_obs.py holds every literal passed to `phase`/`scoped` to it
+PHASES = ("round", "cohort-select", "batch-select", "client-compute",
+          "dp-privatize", "codec-encode", "ef-gather", "ef-scatter",
+          "aggregate", "collective", "head-compute", "surrogate-solve",
+          "round-metrics")
+
+# the round driver's host spans (core/rounds.run_rounds), in the order a
+# chunk runs them; ``rounds/history`` runs once per call
+DRIVER_SPANS = ("rounds/inputs", "rounds/launch", "rounds/eval",
+                "rounds/history")
 
 
 def phase(name: str):
@@ -55,31 +70,35 @@ def scoped(name: str, fn=None):
     return wrapped
 
 
+def host_span(name: str, **attrs):
+    """A host span on the profiler timeline: `jax.profiler.TraceAnnotation`
+    ``name`` with ``attrs`` as its metadata. Emits no row; with the profiler
+    off it costs one annotation object."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
+
+
 class HostSpans:
     """Host-side wall-clock spans at dispatch boundaries.
 
-    Each completed span appends ``{"kind": "span", "span": name,
-    "dur_s": ..., **attrs}`` to :attr:`spans` and, when a stream (any object
-    with ``emit_event(row)``, e.g. `obs.metrics.MetricStream`) is attached,
-    emits the row through it — so the JSONL log carries dispatch timings
-    next to the round rows they bracket. The span body also runs under
-    `jax.profiler.TraceAnnotation(name)`, putting the same name on the
-    profiler timeline.
+    Each completed span emits ``{"kind": "span", "span": name,
+    "dur_s": ..., **attrs}`` through the attached stream (any object with
+    ``emit_event(row)``, e.g. `obs.metrics.MetricStream`), so the JSONL log
+    carries dispatch timings next to the round rows they bracket. The span
+    body also runs under `host_span(name, **attrs)`, putting the same name
+    on the profiler timeline.
     """
 
     def __init__(self, stream=None):
         self.stream = stream
-        self.spans: list = []
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
         t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation(name):
+        with host_span(name, **attrs):
             yield
         row = {"kind": "span", "span": name,
                "dur_s": time.perf_counter() - t0}
         row.update(attrs)
-        self.spans.append(row)
         if self.stream is not None:
             self.stream.emit_event(row)
 
@@ -88,8 +107,7 @@ class HostSpans:
 def profile(logdir: str):
     """Profile the enclosed block with `jax.profiler` into ``logdir``
     (created if missing). The dump contains the `phase` named scopes and
-    every `HostSpans` TraceAnnotation; open it with xprof or
-    ui.perfetto.dev."""
+    every `host_span`; open it with xprof or ui.perfetto.dev."""
     os.makedirs(logdir, exist_ok=True)
     jax.profiler.start_trace(logdir)
     try:

@@ -4,14 +4,19 @@ versus ``obs=None`` — while every streamed row carries exactly the stacked
 metric values (one float32 cast, both transports, both drivers, local and
 sharded topologies). Plus the sink round-trips, the run manifest, eval-row
 interleaving (and the no-silent-shadowing collision check in core/rounds),
-and the launch/feature_dist deprecation shims.
+the round driver's host spans and the phase vocabulary (obs/trace.py), and
+the launch/feature_dist deprecation shims.
 
 On a single-device run (tier-1 CI) the sharded case degenerates to one
 shard; the multi-device CI job (XLA_FLAGS=--xla_force_host_platform_
 device_count=8) runs the same tests with real client distribution.
 """
+import ast
+import glob
 import json
+import re
 import warnings
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +31,9 @@ from repro.core.topology import feature_sharded_for, sharded_for
 from repro.models import mlp
 from repro.obs import (CsvSink, JsonlSink, MemorySink, MetricStream,
                        StdoutSink)
+from repro.obs import HostSpans
 from repro.obs import sinks as obs_sinks
+from repro.obs import trace as obs_trace
 
 P, J, L = 12, 6, 3
 I = 8                                   # sample clients; divisible by 1/2/4/8
@@ -214,6 +221,99 @@ def test_emit_event_direct_and_queued():
     kinds = [r["kind"] for r in stream.rows]
     assert kinds[0] == "span" and kinds[-1] == "span"
     assert kinds[1:-1] == ["round"] * 3
+
+
+def test_host_spans_emit_rows_through_stream():
+    stream = MetricStream([MemorySink()])
+    spans = HostSpans(stream)
+    with spans.span("dispatch", rounds=3, t0=1):
+        pass
+    stream.sync()
+    (row,) = stream.rows
+    assert row["kind"] == "span" and row["span"] == "dispatch"
+    assert row["rounds"] == 3 and row["t0"] == 1 and row["dur_s"] >= 0
+    assert not hasattr(spans, "spans")      # rows live in the stream only
+
+
+# ---------------------------------------------------------------------------
+# phase tracing: the round driver's host spans, the scope vocabulary
+# ---------------------------------------------------------------------------
+
+
+def test_run_rounds_driver_spans_in_order(tmp_path):
+    # 2 chunks of 3 rounds with an eval hook, under the profiler
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _run_alg1(rounds=6, eval_fn=lambda p, s: {"test_acc": 0.5},
+                  eval_every=3)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(path)
+    lines = [[e for e in line.events
+              if e.name in obs_trace.DRIVER_SPANS]
+             for plane in pd.planes if plane.name.startswith("/host:")
+             for line in plane.lines]
+    lines = [evs for evs in lines if evs]
+    assert len(lines) == 1, "driver spans split over host threads"
+    evs = sorted(lines[0], key=lambda e: e.start_ns)
+    inputs, launch, evals, history = obs_trace.DRIVER_SPANS
+    assert [e.name for e in evs] == [inputs, launch, evals] * 2 + [history]
+    assert [dict(e.stats) for e in evs if e.name == launch] == [
+        {"rounds": 3, "t": 1}, {"rounds": 3, "t": 4}]
+
+
+def _scope_literals():
+    """Every string literal passed to `phase(...)`/`scoped(...)` (as a
+    call or a decorator) under src/."""
+    src = Path(obs_trace.__file__).resolve().parents[2]
+    found = set()
+    for path in src.rglob("*.py"):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if not isinstance(node, ast.Call) or not node.args:
+                continue
+            fn = node.func
+            name = fn.attr if isinstance(fn, ast.Attribute) else getattr(
+                fn, "id", None)
+            arg = node.args[0]
+            if name in ("phase", "scoped") and isinstance(arg, ast.Constant) \
+                    and isinstance(arg.value, str):
+                found.add((arg.value, path.name))
+    return found
+
+
+def test_phase_literals_are_the_vocabulary():
+    found = _scope_literals()
+    stray = sorted((n, f) for n, f in found if n not in obs_trace.PHASES)
+    assert not stray, f"scopes missing from obs.trace.PHASES: {stray}"
+    # and PHASES names no scope the program has stopped using
+    assert {n for n, _ in found} == set(obs_trace.PHASES)
+
+
+def test_cohort_int8_step_hlo_upload_scopes():
+    from repro.comm.error_feedback import CommCarry, ef_store_init
+    from repro.core import optimizer
+
+    data = _sample_data(jax.random.PRNGKey(0))
+    params0 = mlp.init(jax.random.PRNGKey(1), P, J, L)
+    fl = _fl()
+    step = algorithms.make_algorithm1_step(
+        mlp.per_sample_loss, data, fl, participation=4,
+        codec=make_codec("int8"), cohort=True)
+    dim = sum(x.size for x in jax.tree.leaves(params0))
+    state = CommCarry(opt=optimizer.ssca_init(params0),
+                      ef=ef_store_init(I, dim))
+    inputs = rounds_lib.make_inputs(fl, 1, 2, jax.random.PRNGKey(2))
+    hlo = rounds_lib._scan_jit(step).lower(state, inputs).as_text(
+        "hlo", debug_info=True)
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    for scope in ("ef-gather", "ef-scatter", "round-metrics"):
+        assert any(f"round/{scope}/" in n for n in names), scope
+    # the new scopes sit beside codec-encode, never inside it, so the
+    # encode's device time keeps exactly its own ops
+    inside = [n for n in names if re.search(
+        r"/codec-encode/(.*/)?(ef-gather|ef-scatter|round-metrics)/", n)]
+    assert not inside, inside[:3]
 
 
 # ---------------------------------------------------------------------------
