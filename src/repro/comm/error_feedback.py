@@ -26,11 +26,13 @@ Two layouts exist for the per-client residual matrix:
   bit-level reference for small I;
 * the **keyed** :class:`EFStore` (``ef_store_init``) for the O(S) cohort
   engine (DESIGN.md §14) — the same ``(I, P)`` backing lives OUTSIDE the
-  per-round compute (device-resident by default, host-offloadable behind
-  the same interface); each round gathers the cohort's ``(S, P)`` slice in
-  and scatters the updated slice back, O(S·P) touched per round. A
-  non-participant's row is never read or written, so the two layouts stay
-  bit-equal (pinned in tests/test_cohort.py).
+  per-round compute, in device memory; each round gathers the cohort's
+  ``(S, P)`` slice in and scatters the updated slice back. On the TPU both
+  are row-copy kernels (``kernels/ef_rows.py``) that touch only the
+  cohort's (8, 128) tile groups, at most 8·S·P·4 bytes per access (a row
+  moves with its tile group), and the scatter updates the backing in
+  place. A non-participant's residual never changes, so the two layouts
+  stay bit-equal (pinned in tests/test_cohort.py).
 
 Ordering with DP (DESIGN.md §15): the ``dp=`` clip+noise stage of
 core/topology.py runs BEFORE ``ef_roundtrip``, so ``target`` — and hence
@@ -43,7 +45,9 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 
 class CommCarry(NamedTuple):
@@ -68,10 +72,17 @@ class EFStore(NamedTuple):
     backing stays out of the round's (S, ...) compute; rounds touch only the
     cohort's rows via :meth:`gather` / :meth:`scatter`.
 
-    A NamedTuple is a registered pytree, so the store rides the scan carry
-    (inside :class:`CommCarry`) unchanged — and because the scatter is the
-    carry's only use of the backing, XLA donates/aliases the buffer across
-    scan iterations: the update is in-place, not an (I, P) copy per round.
+    Where the round is compiled for a TPU, both are row-copy kernels
+    (``kernels/ef_rows.py``) that move the cohort's (8, 128) tile groups
+    and no other byte of the backing; the scatter writes the backing in
+    place through the kernel's input/output alias. A NamedTuple is a
+    registered pytree, so the store rides the scan carry (inside
+    :class:`CommCarry`) and XLA keeps one (I, P) buffer across the scanned
+    rounds. Elsewhere (CPU) they are ``jnp.take`` / ``.at[ids].set``, the
+    reference the kernels are tested against.
+
+    ``ids`` must be distinct and in [0, I), as ``fed.cohort_sample``'s
+    draw without replacement is: the kernels rely on it.
     """
     data: jnp.ndarray              # (I, P) residual backing
 
@@ -83,14 +94,39 @@ class EFStore(NamedTuple):
     def dim(self):
         return self.data.shape[1]
 
-    def gather(self, ids):
-        """(S,) client ids -> (S, P) residual rows for this round's cohort."""
-        return jnp.take(self.data, ids, axis=0)
+    def gather(self, ids, mesh=None):
+        """(S,) client ids -> (S, P) residual rows for this round's cohort.
+        ``mesh``: the devices the round runs on, where there are several
+        (``ShardedTopology.mesh``); the store is replicated over them."""
+        from repro.kernels.ef_rows import ef_rows_gather   # see _rows_op
+        return _rows_op(ef_rows_gather, _take_rows, mesh, self.data, ids)
 
-    def scatter(self, ids, rows):
+    def scatter(self, ids, rows, mesh=None):
         """Write the cohort's updated rows back; every other client's
-        residual is bit-untouched (never read, never written)."""
-        return self._replace(data=self.data.at[ids].set(rows))
+        residual keeps its bits."""
+        from repro.kernels.ef_rows import ef_rows_scatter  # see _rows_op
+        return self._replace(data=_rows_op(
+            ef_rows_scatter, _set_rows, mesh, self.data, ids, rows))
+
+
+def _take_rows(data, ids):
+    return jnp.take(data, ids, axis=0)
+
+
+def _set_rows(data, ids, rows):
+    return data.at[ids].set(rows)
+
+
+def _rows_op(kernel, reference, mesh, *args):
+    """``kernel(*args)`` where the program is compiled for a TPU, else
+    ``reference(*args)``. A Mosaic kernel is not partitioned by XLA, so on a
+    mesh of several devices it runs on each device's replica of the store.
+    The kernels are imported by the methods that use them: Pallas takes
+    1-2 s to import, which a program without a store need not pay."""
+    if mesh is not None and mesh.size > 1:
+        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=PartitionSpec(),
+                               out_specs=PartitionSpec(), check_vma=False)
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=reference)
 
 
 def ef_store_init(num_clients: int, dim: int) -> EFStore:

@@ -133,13 +133,14 @@ def _check_cohort(name: str, cohort: bool, participation):
             "per-round cohort size); pass participation= or drop cohort=")
 
 
-def _cohort_ef_norm(up):
+def _cohort_ef_norm(up, topology):
     """ef_norm for the cohort engine: the norm of the cohort's own updated
     residual rows (O(S·P)) — NOT the full (I, P) backing, which would put an
     O(I) reduction back into every round. Stream semantics therefore differ
     from the dense engine's all-clients norm; don't compare across engines."""
+    mesh = getattr(topology, "mesh", None)
     return _ef_norm(jax.tree.map(
-        lambda store: store.gather(up["cohort"]), up["ef"],
+        lambda store: store.gather(up["cohort"], mesh), up["ef"],
         is_leaf=lambda v: hasattr(v, "gather")))
 
 
@@ -248,7 +249,7 @@ def make_algorithm1_step(per_sample_loss, data: SampleFedData, fl,
                            up, grad_est, data, participation),
                        "axis_bytes": _axis_bytes_metric(topology, grad_est)}
             if codec is not None:
-                metrics["ef_norm"] = (_cohort_ef_norm(up) if cohort
+                metrics["ef_norm"] = (_cohort_ef_norm(up, topology) if cohort
                                       else _ef_norm(up["ef"]))
             if dp is not None:
                 metrics.update(_dp_metrics(eps_fn, up["dp"],
@@ -312,7 +313,7 @@ def make_algorithm2_step(per_sample_loss, data: SampleFedData, fl,
                        "axis_bytes": _axis_bytes_metric(topology, grad_est,
                                                         with_value=True)}
             if codec is not None:
-                metrics["ef_norm"] = (_cohort_ef_norm(up) if cohort
+                metrics["ef_norm"] = (_cohort_ef_norm(up, topology) if cohort
                                       else _ef_norm(up["ef"]))
             if dp is not None:
                 metrics.update(_dp_metrics(eps_fn, up["dp"],
@@ -404,7 +405,8 @@ def algorithm2_general(obj_loss, cons_loss, params0, data: SampleFedData, fl,
                                                            with_value=True))}
             if codec is not None:
                 metrics["ef_norm"] = (
-                    _cohort_ef_norm({"cohort": uo["cohort"], "ef": new_ef})
+                    _cohort_ef_norm({"cohort": uo["cohort"], "ef": new_ef},
+                                    topology)
                     if cohort else _ef_norm(new_ef))
             if dp is not None:
                 pm = uo.get("participants")

@@ -140,13 +140,13 @@ def sample_sgd(per_sample_loss, params0, data: SampleFedData, cfg: SGDConfig,
             ef_rows = None
             if codec is not None and ef is not None:
                 with obs_trace.phase("ef-gather"):
-                    ef_rows = ef.gather(ids)
+                    ef_rows = ef.gather(ids, topo.mesh)
             s = topo.weighted_sum(client_fn, (feats, labs, counts_s, keys), w,
                                   codec=codec, ef=ef_rows, codec_keys=ckeys)
             new_ef = s.ef
             if ef_rows is not None:
                 with obs_trace.phase("ef-scatter"):
-                    new_ef = ef.scatter(ids, s.ef)
+                    new_ef = ef.scatter(ids, s.ef, topo.mesh)
         else:
             keys = fed.client_keys(inp.key, jnp.arange(num_clients))
             w = data.counts.astype(jnp.float32) / jnp.sum(data.counts)

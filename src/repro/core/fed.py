@@ -542,7 +542,7 @@ def cohort_round(per_sample_loss: Callable, params, data, key,
             _check_ef_shape("cohort_round", "q_grad", ef.data,
                             (num_clients, dim))
             with obs_trace.phase("ef-gather"):
-                ef_rows = ef.gather(ids)                              # (S, P)
+                ef_rows = ef.gather(ids, topo.mesh)                   # (S, P)
         if codec_key is None:
             codec_key = jax.random.fold_in(key, 0xC0DEC)
         ckeys = client_keys(codec_key, ids)
@@ -564,7 +564,7 @@ def cohort_round(per_sample_loss: Callable, params, data, key,
     new_ef = s.ef
     if codec is not None and ef is not None:
         with obs_trace.phase("ef-scatter"):
-            new_ef = ef.scatter(ids, s.ef)
+            new_ef = ef.scatter(ids, s.ef, topo.mesh)
     uploads = {"q_grad_sums": s.uploads,
                "q_value_sums": s.values if with_value else None,
                "cohort": ids, "encoded": s.encoded, "ef": new_ef,

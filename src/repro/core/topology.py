@@ -189,6 +189,7 @@ class LocalTopology:
 
     name = "local"
     num_shards = 1
+    mesh = None
 
     def weighted_sum(self, client_fn: Callable, args, weights, *,
                      codec=None, ef=None, codec_keys=None, active=None,

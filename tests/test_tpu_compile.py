@@ -12,12 +12,15 @@ and they all stay in this one file, so one worker loads the library.
 import dataclasses
 import importlib.util
 import os
+import re
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
 from repro.comm.codecs import make_codec, tree_flat_dim
 from repro.configs import FLConfig
@@ -110,3 +113,69 @@ def test_depth_cut_lm_scan_step_fits_one_chip(one_chip):
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert total + 2e9 <= V5E_HBM_BYTES, total
+
+
+EF_CLIENTS = 203
+
+
+def _cohort_int8_step_hlo(sharding, topology=None):
+    """Compiled HLO text of the cohort engine's int8 + EF step (I = 203,
+    S = 32, a 32-16-4 MLP), scanned over 2 rounds, with every input on
+    ``sharding``; and the store's flat dim P."""
+    from repro.comm.error_feedback import CommCarry, ef_store_init
+    from repro.core import algorithms
+    from repro.data.synthetic import VirtualFedData
+
+    data = VirtualFedData(jax.random.PRNGKey(0), EF_CLIENTS,
+                          num_features=32, num_classes=4)
+    fl = FLConfig(batch_size=4)
+    step = algorithms.make_algorithm1_step(
+        mlp.per_sample_loss, data, fl, participation=32,
+        codec=make_codec("int8"), cohort=True, topology=topology)
+    params = jax.eval_shape(lambda: mlp.init(jax.random.PRNGKey(1), 32, 16, 4))
+    dim = tree_flat_dim(params)
+    state = _on(sharding, jax.eval_shape(
+        lambda p: CommCarry(opt=optimizer.ssca_init(p),
+                            ef=ef_store_init(EF_CLIENTS, dim)), params))
+    inputs = _on(sharding, jax.eval_shape(
+        lambda: rounds.make_inputs(fl, 1, 2, jax.random.PRNGKey(2))))
+    return rounds._scan_jit(step).lower(state, inputs).compile().as_text(), dim
+
+
+def test_cohort_int8_step_copies_ef_rows_in_place(one_chip):
+    """Compiled for the chip, the cohort int8 + EF step reads and writes
+    the EF store through the row kernels under their scopes (`ef_norm`'s
+    gather under `round-metrics`), and the (I, P) store is never copied
+    inside the scanned loop: the scatter writes the carry's buffer in
+    place."""
+    hlo, dim = _cohort_int8_step_hlo(one_chip)
+    kernels = re.findall(r"%(ef_rows_\w+)\.\d+ = .*?op_name=\"([^\"]*)\"", hlo)
+    assert sorted((k, re.search(r"round/([\w-]+)/", n).group(1))
+                  for k, n in kernels) == [
+        ("ef_rows_gather", "ef-gather"), ("ef_rows_gather", "round-metrics"),
+        ("ef_rows_scatter", "ef-scatter")]
+    # no pass over the whole store (a copy, or a fusion that makes one)
+    # in any loop body
+    store = re.compile(rf"= f32\[{EF_CLIENTS},{dim}\]\S* (copy|copy-start|"
+                       r"fusion)\(")
+    bodies = set(re.findall(r"\bbody=%?([\w.\-]+)", hlo))
+    computation = None
+    for line in hlo.splitlines():
+        if line and not line[0].isspace():
+            computation = line.removeprefix("ENTRY ").split()[0].lstrip("%")
+        elif computation in bodies:
+            assert not store.search(line), line
+
+
+def test_sharded_cohort_int8_step_compiles_for_four_chips(topo):
+    """Under `ShardedTopology` the EF store stays replicated and XLA cannot
+    partition a Mosaic kernel: the row kernels run on each chip's replica
+    (inside a shard_map over the topology's mesh), and the step compiles
+    for a 2x2 v5e."""
+    from repro.core.topology import ShardedTopology
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    hlo, _ = _cohort_int8_step_hlo(NamedSharding(mesh, PartitionSpec()),
+                                   ShardedTopology(mesh))
+    assert sorted(re.findall(r"%(ef_rows_\w+)\.\d+ = ", hlo)) == [
+        "ef_rows_gather", "ef_rows_gather", "ef_rows_scatter"]
