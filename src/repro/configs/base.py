@@ -28,6 +28,15 @@ class ModelConfig:
     moe_d_ff: int = 0                 # per-expert hidden dim (d_ff used for dense part)
     dense_residual: bool = False      # arctic-style dense MLP in parallel with MoE
     capacity_factor: float = 1.25
+    # dropless routing over a held expert range (DeepSeek-V3 style): the
+    # layer routes over all n_experts and computes the part of experts
+    # [expert_shard * experts_held, +experts_held); 0 = capacity routing
+    experts_held: int = 0
+    expert_shard: int = 0
+    n_shared_experts: int = 0         # always-on experts, width n * moe_d_ff
+    first_dense_layers: int = 0       # leading dense-FFN layers before the MoE stack
+    router_scoring: str = "softmax"   # softmax | sigmoid
+    routed_scaling: float = 1.0       # multiplies the normalized top-k weights
     # --- architecture details ---
     activation: str = "swiglu"        # swiglu | geglu | gelu
     qkv_bias: bool = False
@@ -36,6 +45,12 @@ class ModelConfig:
     tie_embeddings: bool = True
     # --- attention variant ---
     sliding_window: int = 0           # 0 = full/causal attention
+    # multi-head latent attention (MLA) when kv_lora_rank > 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    embed_scale: bool = True          # multiply embeddings by sqrt(d_model)
     # --- SSM / hybrid ---
     ssm_state: int = 0
     ssm_heads: int = 0                # number of SSM heads (mamba2/mLSTM)
@@ -87,6 +102,12 @@ class ModelConfig:
             vocab_size=min(self.vocab_size, 512),
             n_experts=min(self.n_experts, 4),
             experts_per_token=min(self.experts_per_token, 2),
+            experts_held=min(self.experts_held, 4),
+            expert_shard=0,
+            kv_lora_rank=min(self.kv_lora_rank, 64),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+            v_head_dim=min(self.v_head_dim, 32),
             moe_d_ff=min(self.moe_d_ff, 128) if self.moe_d_ff else 0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_heads=min(self.ssm_heads, 4) if self.ssm_heads else 0,

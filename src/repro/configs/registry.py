@@ -1,6 +1,6 @@
 """Architecture registry: --arch <id> resolution."""
 from repro.configs import (arctic_480b, deepseek_67b, gemma_7b, glm4_9b,
-                           mnist_mlp, paligemma_3b, qwen2_5_3b,
+                           mnist_mlp, moonlight_16b_a3b, paligemma_3b, qwen2_5_3b,
                            qwen3_moe_30b_a3b, seamless_m4t_medium, xlstm_1_3b,
                            zamba2_1_2b)
 
@@ -16,6 +16,7 @@ ARCHS = {
     "glm4-9b": glm4_9b.CONFIG,
     "glm4-9b-swa": glm4_9b.LONG_VARIANT,     # beyond-paper long-context variant
     "zamba2-1.2b": zamba2_1_2b.CONFIG,
+    "moonlight-16b-a3b": moonlight_16b_a3b.CONFIG,
     "mnist-mlp": mnist_mlp.CONFIG,           # the paper's own model
 }
 

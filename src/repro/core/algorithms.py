@@ -144,6 +144,14 @@ def _cohort_ef_norm(up, topology):
         is_leaf=lambda v: hasattr(v, "gather")))
 
 
+def _client_stats(stats):
+    """Round totals of the per-client stats the client loss returned:
+    ``*_max`` is the largest over clients, the rest are summed (empty for
+    losses that return none)."""
+    return {k: (jnp.max if k.endswith("_max") else jnp.sum)(v)
+            for k, v in (stats or {}).items()}
+
+
 def _stat_res(new_params, old_params, gamma_t):
     """Per-round stationarity residual ‖ω^{t+1} − ω^t‖₂ / γ^t = ‖ω̄^t − ω^t‖₂
     (the update is ω ← (1−γ)ω + γω̄, eq. 5) — the quantity Theorems 1/2
@@ -247,7 +255,8 @@ def make_algorithm1_step(per_sample_loss, data: SampleFedData, fl,
                                              inp.gamma),
                        "upload_bytes": _sample_upload_bytes(
                            up, grad_est, data, participation),
-                       "axis_bytes": _axis_bytes_metric(topology, grad_est)}
+                       "axis_bytes": _axis_bytes_metric(topology, grad_est),
+                       **_client_stats(up["client_stats"])}
             if codec is not None:
                 metrics["ef_norm"] = (_cohort_ef_norm(up, topology) if cohort
                                       else _ef_norm(up["ef"]))

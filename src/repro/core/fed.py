@@ -294,6 +294,20 @@ def client_keys(key, ids):
 # ---------------------------------------------------------------------------
 
 
+def _client_upload(per_sample_loss, params, zb, yb, mask):
+    """A client's (q, Σ f, stats) over its masked batch: q = Σ_n ∇f(ω; x_n).
+    ``per_sample_loss`` returns the per-row losses, or (per-row losses,
+    stats) where the model counts work of its own (a language model's
+    expert slots); stats come back per client, {} where there are none."""
+    def batch_sum_loss(p):
+        out = per_sample_loss(p, zb, yb)
+        per_row, stats = out if isinstance(out, tuple) else (out, {})
+        return jnp.sum(per_row * mask), stats
+
+    (val, stats), q = jax.value_and_grad(batch_sum_loss, has_aux=True)(params)
+    return q, val, stats
+
+
 def sample_batches(data: SampleFedData, key, batch_size: int):
     """Step 4: each client randomly selects a mini-batch N_i^(t). Keys are
     derived per client id (`client_keys`) so the cohort engine draws the
@@ -403,12 +417,7 @@ def sample_round(per_sample_loss: Callable, params, data: SampleFedData, key,
     def client(feat_i, lab_i, idx_i, mask_i):
         zb = jnp.take(feat_i, idx_i, axis=0)
         yb = jnp.take(lab_i, idx_i, axis=0)
-
-        def batch_sum_loss(p):
-            return jnp.sum(per_sample_loss(p, zb, yb) * mask_i)
-
-        val, q = jax.value_and_grad(batch_sum_loss)(params)
-        return q, val
+        return _client_upload(per_sample_loss, params, zb, yb, mask_i)
 
     pmask = None
     # S >= I degrades to full participation (the I/S reweighting is exactly 1)
@@ -443,7 +452,8 @@ def sample_round(per_sample_loss: Callable, params, data: SampleFedData, key,
                           dp=dp, dp_keys=dkeys, dp_scale=dscale)
     uploads = {"q_grad_sums": s.uploads,
                "q_value_sums": s.values if with_value else None,
-               "participants": pmask, "encoded": s.encoded, "ef": s.ef,
+               "client_stats": s.aux, "participants": pmask,
+               "encoded": s.encoded, "ef": s.ef,
                "dp": s.dp, "upload_nbytes": nbytes}
     return s.weighted, s.value, uploads
 
@@ -523,11 +533,7 @@ def cohort_round(per_sample_loss: Callable, params, data, key,
         zb, yb = data.batch_rows(ids, idx)            # (S, B, P), (S, B, L)
 
     def client(zb_i, yb_i, mask_i):
-        def batch_sum_loss(p):
-            return jnp.sum(per_sample_loss(p, zb_i, yb_i) * mask_i)
-
-        val, q = jax.value_and_grad(batch_sum_loss)(params)
-        return q, val
+        return _client_upload(per_sample_loss, params, zb_i, yb_i, mask_i)
 
     ckeys = active = ef_rows = None
     nbytes = None
@@ -567,7 +573,8 @@ def cohort_round(per_sample_loss: Callable, params, data, key,
             new_ef = ef.scatter(ids, s.ef, topo.mesh)
     uploads = {"q_grad_sums": s.uploads,
                "q_value_sums": s.values if with_value else None,
-               "cohort": ids, "encoded": s.encoded, "ef": new_ef,
+               "client_stats": s.aux, "cohort": ids,
+               "encoded": s.encoded, "ef": new_ef,
                "dp": s.dp, "upload_nbytes": nbytes}
     return s.weighted, s.value, uploads
 

@@ -82,9 +82,11 @@ def scan_rounds(step_fn: Callable, state, inputs: RoundInputs):
     step_fn(state, inp) -> (state, metrics-dict-of-scalars); returns the final
     state and the metrics dict stacked to (K,) arrays. The jitted callable is
     cached per step_fn identity (bounded LRU), so chunked callers and repeat
-    invocations with the same step compile once.
+    invocations with the same step compile once. A state too large to hold
+    twice is donated (`scan_jit_for`): the caller's ``state`` is then
+    consumed.
     """
-    return _scan_jit(step_fn)(state, inputs)
+    return scan_jit_for(step_fn, state)(state, inputs)
 
 
 # Caches keyed weakly by step_fn identity. Cross-CALL reuse (not just within
@@ -97,7 +99,12 @@ def scan_rounds(step_fn: Callable, state, inputs: RoundInputs):
 # callable itself only holds a weakref to step_fn, which is live whenever
 # the entry is reachable.
 _SCAN_CACHE = weakref.WeakKeyDictionary()
+_SCAN_DONATED_CACHE = weakref.WeakKeyDictionary()
 _STEP_CACHE = weakref.WeakKeyDictionary()
+
+# a carried state above this share of the device's memory is donated to the
+# K-round program: held by the caller too, it would be in memory twice
+DONATE_SHARE = 1 / 8
 
 
 def _weak_cached(cache, step_fn, make):
@@ -116,6 +123,30 @@ def _scan_jit(step_fn):
         lambda ref: jax.jit(
             lambda state, inputs: jax.lax.scan(
                 obs_trace.scoped("round", ref()), state, inputs)))
+
+
+def _scan_jit_donated(step_fn):
+    """`_scan_jit` whose program takes over (donates) the carried state."""
+    return _weak_cached(
+        _SCAN_DONATED_CACHE, step_fn,
+        lambda ref: jax.jit(
+            lambda state, inputs: jax.lax.scan(
+                obs_trace.scoped("round", ref()), state, inputs),
+            donate_argnums=0))
+
+
+def donates(state) -> bool:
+    """Whether the K-round program donates ``state``: its bytes exceed
+    DONATE_SHARE of the device's memory (a language model's parameters and
+    surrogate buffer). Smaller states stay the caller's to reuse."""
+    from repro.core.topology import device_bytes
+    nbytes = sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(state))
+    return nbytes > DONATE_SHARE * device_bytes()
+
+
+def scan_jit_for(step_fn, state):
+    """The jitted K-round program of ``step_fn`` for ``state``."""
+    return (_scan_jit_donated if donates(state) else _scan_jit)(step_fn)
 
 
 def _step_jit(step_fn):

@@ -66,6 +66,7 @@ shard exactly like the sample-based path.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable, NamedTuple, Optional, Sequence
 
@@ -84,10 +85,68 @@ class ClientSums(NamedTuple):
     weighted: object          # Σ_i w_i û_i — server aggregate (pytree)
     value: jnp.ndarray        # Σ_i w_i val_i — scalar aggregate
     uploads: object           # per-client û_i, stacked (I, ...) pytree
+                              # (None when the sum ran in client blocks)
     values: jnp.ndarray       # per-client val_i, (I,)
     encoded: object           # codec wire format per client (None if dense)
     ef: object                # updated EF residuals (I, P) (None if dense)
     dp: object = None         # clip/noise stats per client (None if no DP)
+    aux: object = None        # per-client stats the client_fn returned, (I,)
+
+
+# the stacked uploads of one block of clients may take this share of the
+# device's memory; LocalTopology sums larger cohorts block by block
+UPLOAD_BLOCK_SHARE = 1 / 8
+
+
+@functools.cache
+def device_bytes() -> int:
+    """Memory of the device the clients run on (16 GiB where the backend
+    keeps no statistics, as the CPU backend); read once, since
+    `rounds.donates` asks on every dispatch."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", 16 * 2**30))
+
+
+def client_block(num_clients: int, upload_bytes: int, budget=None) -> int:
+    """Clients per block of the server sum: the largest divisor of
+    ``num_clients`` whose stacked uploads (``upload_bytes`` each) fit in
+    ``budget`` (default: UPLOAD_BLOCK_SHARE of the device), at least 1."""
+    if budget is None:
+        budget = UPLOAD_BLOCK_SHARE * device_bytes()
+    blk = num_clients
+    while blk > 1 and (blk * upload_bytes > budget or num_clients % blk):
+        blk -= 1
+    return blk
+
+
+def _run_clients(client_fn, args):
+    """vmap of client_fn, which returns (upload, val) or (upload, val, aux):
+    (uploads, values, aux) stacked over the client axis."""
+    out = jax.vmap(client_fn)(*args)
+    return out if len(out) == 3 else (*out, {})
+
+
+def _lanes(client_fn, codec, dp, args, weights, ef, codec_keys, active,
+           dp_keys, dp_scale):
+    """The per-client stages over a stack of clients, then their weighted
+    sum: client compute, DP clip + noise, codec encode with EF, Σ w_i û_i.
+    Returns the fields of :class:`ClientSums` in order. Identical code runs
+    for LocalTopology (whole or per block) and inside each shard_map
+    shard."""
+    with obs_trace.phase("client-compute"):
+        uploads, values, aux = _run_clients(client_fn, args)
+    enc = new_ef = dp_stats = None
+    if dp is not None:
+        with obs_trace.phase("dp-privatize"):
+            uploads, dp_stats = _privatize_stacked(dp, uploads, dp_keys,
+                                                   dp_scale)
+    if codec is not None:
+        with obs_trace.phase("codec-encode"):
+            enc, uploads, new_ef = _compress_stacked(codec, uploads, ef,
+                                                     codec_keys, active)
+    with obs_trace.phase("aggregate"):
+        weighted, value = _weighted(weights, uploads, values)
+    return weighted, value, uploads, values, enc, new_ef, dp_stats, aux
 
 
 def _compress_stacked(codec, uploads, ef, codec_keys, active):
@@ -194,25 +253,45 @@ class LocalTopology:
     def weighted_sum(self, client_fn: Callable, args, weights, *,
                      codec=None, ef=None, codec_keys=None, active=None,
                      dp=None, dp_keys=None, dp_scale=None) -> ClientSums:
-        """client_fn(*per_client_args) -> (upload pytree, val scalar); args
-        are (I, ...)-leading arrays; returns all of :class:`ClientSums`.
-        With ``dp=`` (a privacy.DPConfig) each client's upload is
-        clipped+noised at the client boundary BEFORE any codec encode."""
-        with obs_trace.phase("client-compute"):
-            uploads, values = jax.vmap(client_fn)(*args)
-        enc = new_ef = dp_stats = None
-        if dp is not None:
-            with obs_trace.phase("dp-privatize"):
-                uploads, dp_stats = _privatize_stacked(dp, uploads, dp_keys,
-                                                       dp_scale)
-        if codec is not None:
-            with obs_trace.phase("codec-encode"):
-                enc, uploads, new_ef = _compress_stacked(codec, uploads, ef,
-                                                         codec_keys, active)
+        """client_fn(*per_client_args) -> (upload pytree, val scalar[, aux
+        stats]); args are (I, ...)-leading arrays; returns all of
+        :class:`ClientSums`. With ``dp=`` (a privacy.DPConfig) each
+        client's upload is clipped+noised at the client boundary BEFORE any
+        codec encode.
+
+        Where the I stacked uploads would not fit in UPLOAD_BLOCK_SHARE of
+        the device (I·P·4 bytes), the clients run in blocks under a scan
+        that keeps a running Σ w_i û_i: DP, codec and EF stay per client
+        inside a block, so the wire bytes are the same, and ``uploads``
+        comes back None. Otherwise it is one block, the plain vmap."""
+        def lanes(*per_client):
+            return _lanes(client_fn, codec, dp, *per_client)
+
+        num = weights.shape[0]
+        one = jax.eval_shape(client_fn, *[a[0] for a in args])[0]
+        blk = client_block(num, 4 * comm_codecs.tree_flat_dim(one))
+        per_client = (tuple(args), weights, ef, codec_keys, active, dp_keys,
+                      dp_scale)
+        if blk == num:
+            return ClientSums(*lanes(*per_client))
+
+        def body(acc, xs):
+            weighted, _, _, *rest = lanes(*xs)
+            with obs_trace.phase("aggregate"):
+                acc = jax.tree.map(jnp.add, acc, weighted)
+            return acc, rest
+
+        blocks = jax.tree.map(
+            lambda a: a.reshape(num // blk, blk, *a.shape[1:]), per_client)
+        zero = jax.tree.map(lambda u: jnp.zeros(u.shape, jnp.float32), one)
+        weighted, rest = jax.lax.scan(body, zero, blocks)
+        values, enc, new_ef, dp_stats, aux = jax.tree.map(
+            lambda a: a.reshape(num, *a.shape[2:]), rest)
         with obs_trace.phase("aggregate"):
-            weighted, value = _weighted(weights, uploads, values)
-        return ClientSums(weighted=weighted, value=value, uploads=uploads,
-                          values=values, encoded=enc, ef=new_ef, dp=dp_stats)
+            value = jnp.dot(weights, values)
+        return ClientSums(weighted=weighted, value=value, uploads=None,
+                          values=values, encoded=enc, ef=new_ef, dp=dp_stats,
+                          aux=aux)
 
     def feature_sum(self, h_fn: Callable, head_fn: Callable,
                     block_grad_fn: Callable, blocks, zb, *,
@@ -330,37 +409,22 @@ class ShardedTopology:
         self._check_divisible(weights.shape[0])
         axes = self.axes
         spec = P(axes)
-        has_codec = codec is not None
-        has_dp = dp is not None
 
-        def body(args_l, weights_l, ef_l, keys_l, act_l, dpk_l, dps_l):
-            with obs_trace.phase("client-compute"):
-                uploads, values = jax.vmap(client_fn)(*args_l)
-            enc = new_ef = dp_stats = None
-            if has_dp:
-                with obs_trace.phase("dp-privatize"):
-                    uploads, dp_stats = _privatize_stacked(dp, uploads,
-                                                           dpk_l, dps_l)
-            if has_codec:
-                with obs_trace.phase("codec-encode"):
-                    enc, uploads, new_ef = _compress_stacked(
-                        codec, uploads, ef_l, keys_l, act_l)
-            with obs_trace.phase("aggregate"):
-                partial, val_partial = _weighted(weights_l, uploads, values)
+        def body(*per_client):
+            partial, val_partial, *rest = _lanes(client_fn, codec, dp,
+                                                 *per_client)
             with obs_trace.phase("collective"):
                 weighted = jax.lax.psum(partial, axes)
                 value = jax.lax.psum(val_partial, axes)
-            return weighted, value, uploads, values, enc, new_ef, dp_stats
+            return (weighted, value, *rest)
 
         sharded = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(spec, spec, spec, spec, spec, spec, spec),
-            out_specs=(P(), P(), spec, spec, spec, spec, spec),
+            out_specs=(P(), P(), spec, spec, spec, spec, spec, spec),
             check_vma=False)
-        weighted, value, uploads, values, enc, new_ef, dp_stats = sharded(
-            tuple(args), weights, ef, codec_keys, active, dp_keys, dp_scale)
-        return ClientSums(weighted=weighted, value=value, uploads=uploads,
-                          values=values, encoded=enc, ef=new_ef, dp=dp_stats)
+        return ClientSums(*sharded(tuple(args), weights, ef, codec_keys,
+                                   active, dp_keys, dp_scale))
 
     def place_feature_state(self, state):
         """Pre-place a feature-based `CommCarry`'s EF residual dict: the

@@ -192,6 +192,75 @@ class VirtualFedData:
         return fed.SampleFedData(feats, labs, counts)
 
 
+class VirtualTokenData:
+    """Virtual cross-silo LM population: silo i holds N_i packed sequences of
+    ``seq_len`` tokens, each row a pure function of (key, silo id, row), so
+    the cohort engine reads it exactly as it reads `VirtualFedData`
+    (``counts_for``, ``batch_rows``, ``num_clients``, ``total``).
+
+    * N_i is heavy-tailed: floor(n_min / (1 - u)^(1/tail)) capped at n_max,
+      u ~ Uniform[0, 1) keyed by silo id (tail 1: a Pareto tail, so a few
+      silos hold most of the sequences).
+    * Tokens follow a silo's own Markov chain over the vocabulary: silo i
+      draws a (vocab, fanout) successor table from its key; row r starts at
+      a uniform token and takes one of the current token's ``fanout``
+      successors uniformly at each step. Documents are concatenated with no
+      mask between them (a packed row is one causal sequence).
+
+    ``batch_rows`` returns (tokens, targets), each (S, B, seq_len): targets
+    are the tokens shifted by one.
+    """
+
+    def __init__(self, key, num_clients: int, seq_len: int, vocab_size: int,
+                 n_min: int = 64, n_max: int = 1024, tail: float = 1.0,
+                 fanout: int = 4):
+        if n_min < 1 or n_max < n_min:
+            raise ValueError(f"need 1 <= n_min <= n_max, got [{n_min}, {n_max}]")
+        self.key = key
+        self.num_clients = int(num_clients)
+        self.seq_len, self.vocab_size = int(seq_len), int(vocab_size)
+        self.n_min, self.n_max = int(n_min), int(n_max)
+        self.tail, self.fanout = float(tail), int(fanout)
+        self.total = int(jnp.sum(self.counts_for(
+            jnp.arange(self.num_clients, dtype=jnp.int32))))
+
+    def _count(self, i):
+        u = jax.random.uniform(jax.random.fold_in(
+            jax.random.fold_in(self.key, i), 2))
+        n = jnp.floor(self.n_min / (1.0 - u) ** (1.0 / self.tail))
+        return jnp.minimum(n, self.n_max).astype(jnp.int32)
+
+    def _row(self, ck, succ, r):
+        kr = jax.random.fold_in(jax.random.fold_in(ck, 3), r)
+        start = jax.random.randint(jax.random.fold_in(kr, 0), (), 0,
+                                   self.vocab_size)
+        picks = jax.random.randint(jax.random.fold_in(kr, 1),
+                                   (self.seq_len,), 0, self.fanout)
+
+        def step(tok, c):
+            nxt = succ[tok, c]
+            return nxt, nxt
+
+        _, rest = jax.lax.scan(step, start, picks)
+        seq = jnp.concatenate([start[None], rest])
+        return seq[:-1], seq[1:]
+
+    def _client_rows(self, i, idx):
+        ck = jax.random.fold_in(self.key, i)
+        succ = jax.random.randint(jax.random.fold_in(ck, 1),
+                                  (self.vocab_size, self.fanout), 0,
+                                  self.vocab_size)
+        return jax.vmap(lambda r: self._row(ck, succ, r))(idx)
+
+    def counts_for(self, ids):
+        """(S,) true N_i for the given silo ids."""
+        return jax.vmap(self._count)(ids)
+
+    def batch_rows(self, ids, idx):
+        """(S,) ids + (S, B) row indices -> (tokens, targets), (S, B, T)."""
+        return jax.vmap(self._client_rows)(ids, idx)
+
+
 def token_dataset(key, vocab_size: int, n_tokens: int, order: int = 1):
     """Markov bigram stream: next-token depends on current via a random sparse
     transition; gives a learnable LM signal with nonzero optimal loss."""

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.obs.trace import phase
+
 # ---------------------------------------------------------------------------
 # ambient-mesh activation sharding
 # ---------------------------------------------------------------------------
@@ -291,7 +293,7 @@ def _cattn_fwd_scan(qt, kb, vb, kp, qp, scale, causal, window, prefix_len):
 
     m0 = jnp.full((b, h, sq), -1e30, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
-    a0 = jnp.zeros((b, h, sq, hd), jnp.float32)
+    a0 = jnp.zeros((b, h, sq, vb.shape[-1]), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0), (kb, vb, kp))
     lse = m + jnp.log(jnp.where(l == 0.0, 1.0, l))              # logsumexp rows
     out = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
@@ -357,11 +359,12 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, causal=True, window=0,
     working set is (Sq, block). This is the jnp mirror of
     kernels/flash_attention.py (which replaces it on real TPU).
 
-    q: (B,Sq,H,Hd); k,v: (B,Sk,H,Hd) (already GQA-broadcast);
-    q_pos/k_pos: (1, Sq)/(1, Sk). Returns (B,Sq,H,Hd).
+    q,k: (B,Sq|Sk,H,Hd); v: (B,Sk,H,Hv) (already GQA-broadcast; Hv may
+    differ from Hd, as in latent attention); q_pos/k_pos: (1, Sq)/(1, Sk).
+    Returns (B,Sq,H,Hv).
     """
     b, sq, h, hd = q.shape
-    sk = k.shape[1]
+    sk, hv = k.shape[1], v.shape[-1]
     blk = min(block, sk)
     pad = (-sk) % blk
     if pad:
@@ -374,7 +377,7 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, causal=True, window=0,
     q = shard_spec(q, qspec)
     qt = q.transpose(0, 2, 1, 3).astype(jnp.float32)            # (B,H,Sq,Hd)
     kb = k.reshape(b, n, blk, h, hd).transpose(1, 0, 3, 2, 4).astype(jnp.float32)
-    vb = v.reshape(b, n, blk, h, hd).transpose(1, 0, 3, 2, 4).astype(jnp.float32)
+    vb = v.reshape(b, n, blk, h, hv).transpose(1, 0, 3, 2, 4).astype(jnp.float32)
     kp = k_pos.reshape(1, n, blk).transpose(1, 0, 2)            # (N,1,blk)
     qp = q_pos[..., :, None]                                    # (1,Sq,1)
     out = _cattn(qt, kb, vb, kp, qp, causal, window, prefix_len)
@@ -439,6 +442,86 @@ def attention_decode(params, x, cache_k, cache_v, pos, cfg, *, window=0):
 
 
 # ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2/V3 MLA, no query compression)
+# ---------------------------------------------------------------------------
+
+
+def mla_init(key, cfg, dtype):
+    """q = x Wq (H heads of nope + rope dims); [c_kv, k_pe] = x Wkv_a;
+    [k_nope, v] = RMSNorm(c_kv) Wkv_b per head; out = concat_h(o_h) Wo."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], (d, h * (nope + rd)), dtype, fan_in=d),
+        "wkv_a": dense_init(ks[1], (d, r + rd), dtype, fan_in=d),
+        "kv_norm": rmsnorm_init(r, dtype),
+        "wkv_b": dense_init(ks[2], (r, h * (nope + vd)), dtype, fan_in=r),
+        "wo": dense_init(ks[3], (h * vd, d), dtype, fan_in=h * vd),
+    }
+
+
+def _mla_q(params, x, positions, cfg):
+    b, s, _ = x.shape
+    nope = cfg.qk_nope_head_dim
+    q = (x @ params["wq"]).reshape(b, s, cfg.n_heads, -1)
+    return jnp.concatenate(
+        [q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
+
+
+def mla_latent(params, x, positions, cfg):
+    """What MLA caches per token: the normed latent c_kv (B, S, r) and the
+    RoPE'd key part k_pe (B, S, rope), which every head shares."""
+    r = cfg.kv_lora_rank
+    kva = x @ params["wkv_a"]
+    c_kv = rmsnorm(params["kv_norm"], kva[..., :r], cfg.norm_eps)
+    k_pe = rope(kva[..., None, r:], positions, cfg.rope_theta)[..., 0, :]
+    return c_kv, k_pe
+
+
+def _mla_kv(params, c_kv, k_pe, cfg):
+    b, s, _ = c_kv.shape
+    h, nope = cfg.n_heads, cfg.qk_nope_head_dim
+    kv = (c_kv @ params["wkv_b"]).reshape(b, s, h, -1)
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :], (b, s, h, k_pe.shape[-1]))
+    return jnp.concatenate([kv[..., :nope], k_pe], -1), kv[..., nope:]
+
+
+def mla_attention(params, x, positions, cfg):
+    """Causal MLA over a full sequence; scores scale 1/sqrt(nope + rope).
+    With ``attention_impl="chunked"`` no (H, S, S) array is ever held."""
+    b, s, _ = x.shape
+    with phase("mla-attention"):
+        q = _mla_q(params, x, positions, cfg)
+        k, v = _mla_kv(params, *mla_latent(params, x, positions, cfg), cfg)
+        if cfg.attention_impl == "chunked":
+            out = chunked_attention(q, k, v, positions, positions, causal=True,
+                                    block=cfg.attention_block)
+        else:
+            mask = make_attention_mask(positions, positions, causal=True)
+            out = dot_attention(q, k, v, mask, kv_heads_repeat=1)
+        return out.reshape(b, s, -1) @ params["wo"]
+
+
+def mla_decode(params, x, cache_c, cache_pe, pos, cfg):
+    """One-token MLA decode against the latent cache: cache_c (B, S_max, r),
+    cache_pe (B, S_max, rope). Returns (out, cache_c, cache_pe)."""
+    b = x.shape[0]
+    p1 = jnp.full((b, 1), pos, jnp.int32)
+    q = _mla_q(params, x, p1, cfg)
+    c_kv, k_pe = mla_latent(params, x, p1, cfg)
+    cache_c = jax.lax.dynamic_update_slice(
+        cache_c, c_kv.astype(cache_c.dtype), (0, pos, 0))
+    cache_pe = jax.lax.dynamic_update_slice(
+        cache_pe, k_pe.astype(cache_pe.dtype), (0, pos, 0))
+    k, v = _mla_kv(params, cache_c.astype(q.dtype), cache_pe.astype(q.dtype),
+                   cfg)
+    mask = (jnp.arange(cache_c.shape[1]) <= pos)[None, None, :]
+    out = dot_attention(q, k, v, mask, kv_heads_repeat=1)
+    return out.reshape(b, 1, -1) @ params["wo"], cache_c, cache_pe
+
+
+# ---------------------------------------------------------------------------
 # MLP blocks
 # ---------------------------------------------------------------------------
 
@@ -471,17 +554,146 @@ def mlp(params, x, activation: str):
 
 
 def moe_init(key, cfg, dtype):
+    """Router over all n_experts; expert weights for the experts this layer
+    holds (``experts_held``, else all); shared experts as one SwiGLU of
+    width n_shared_experts * moe_d_ff."""
     d, e, ff = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    held = cfg.experts_held or e
     ks = jax.random.split(key, 4)
     p = {
         "router": dense_init(ks[0], (d, e), dtype, fan_in=d),
-        "wi": dense_init(ks[1], (e, d, ff), dtype, fan_in=d),
-        "wg": dense_init(ks[2], (e, d, ff), dtype, fan_in=d),
-        "wo": dense_init(ks[3], (e, ff, d), dtype, fan_in=ff),
+        "wi": dense_init(ks[1], (held, d, ff), dtype, fan_in=d),
+        "wg": dense_init(ks[2], (held, d, ff), dtype, fan_in=d),
+        "wo": dense_init(ks[3], (held, ff, d), dtype, fan_in=ff),
     }
     if cfg.dense_residual:
         p["dense"] = mlp_init(jax.random.fold_in(key, 7), d, cfg.d_ff, "swiglu", dtype)
+    if cfg.n_shared_experts:
+        p["shared"] = mlp_init(jax.random.fold_in(key, 11), d,
+                               cfg.n_shared_experts * ff, "swiglu", dtype)
     return p
+
+
+def _one_at_a_time(fn):
+    """fn, batched by a loop over the batch axis: `jax.lax.ragged_dot`
+    batches only where its weights are batched too, and a vmapped client
+    shares them."""
+    f = jax.custom_batching.custom_vmap(fn)
+
+    @f.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        def one(batched):
+            it = iter(batched)
+            return fn(*[next(it) if b else a
+                        for a, b in zip(args, in_batched)])
+        out = jax.lax.map(one, [a for a, b in zip(args, in_batched) if b])
+        return out, jax.tree.map(lambda _: True, out)
+
+    return f
+
+
+def _grouped_rows(y, sizes):
+    """y with its rows past sum(sizes) set to 0: the TPU's grouped-product
+    kernel leaves those rows unwritten."""
+    rows = jnp.arange(y.shape[0])[:, None] < jnp.sum(sizes)
+    return jnp.where(rows, y, jnp.zeros((), y.dtype))
+
+
+def _ragged(x, w, sizes):
+    return _grouped_rows(jax.lax.ragged_dot(
+        x, w, sizes, preferred_element_type=jnp.float32), sizes)
+
+
+def _ragged_grads(x, w, sizes, g):
+    """(dx, dw) of the grouped product: its products take bfloat16 operands
+    too (the cotangent rounded), each accumulating in float32 before its
+    bfloat16 result."""
+    dx, dw = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, sizes), x, w)[1](
+        g.astype(x.dtype))
+    return _grouped_rows(dx, sizes), dw
+
+
+_ragged_fwd = _one_at_a_time(_ragged)
+_ragged_bwd = _one_at_a_time(_ragged_grads)
+
+
+@jax.custom_vjp
+def _ragged_dot(x, w, sizes):
+    return _ragged_fwd(x, w, sizes)
+
+
+def _ragged_dot_fwd(x, w, sizes):
+    return _ragged_fwd(x, w, sizes), (x, w, sizes)
+
+
+def _ragged_dot_bwd(res, g):
+    x, w, sizes = res
+    dx, dw = _ragged_bwd(x, w, sizes, g)
+    return dx, dw, None
+
+
+_ragged_dot.defvjp(_ragged_dot_fwd, _ragged_dot_bwd)
+
+
+def _gmm(x, w, sizes):
+    """Grouped product of expert-sorted rows with their experts' weights:
+    one bfloat16 pass, float32 accumulation. Rows past sum(sizes) come out
+    0, and so do their gradients."""
+    return _ragged_dot(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16), sizes)
+
+
+def moe_held(params, x, cfg):
+    """Dropless MoE over the held expert range (DeepSeek-V3 routing).
+
+    Every token is routed over all ``n_experts``: s = sigmoid(x W_r) (the
+    gate in float32, as published), the top ``experts_per_token`` of s + b
+    (b, the correction bias, is a fixed buffer; zero here, so the pick is
+    the top-k of s), weights s[top] / sum s[top] * routed_scaling. This
+    layer computes the part of experts [expert_shard * experts_held,
+    +experts_held): the token-slots routed to them are sorted by expert and
+    run through grouped products; slots of absent experts add nothing (an
+    expert-parallel peer computes them). No capacity, no dropped slot, no
+    auxiliary loss. Shared experts run on every token.
+
+    x: (B, S, D). Returns (out, stats): per-layer counts of the held slots
+    (``moe_slots_held``), the busiest held expert over the mean
+    (``moe_load_max``) and routed-but-not-computed slots (``moe_dropped``,
+    0 by construction).
+    """
+    b, s, d = x.shape
+    e, k, held = cfg.n_experts, cfg.experts_per_token, cfg.experts_held
+    t = b * s
+    xt = x.reshape(t, d)
+    with phase("moe-dispatch"):
+        logits = jnp.matmul(xt.astype(jnp.float32),
+                            params["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        scores = (jax.nn.sigmoid(logits) if cfg.router_scoring == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        top_s, top_e = jax.lax.top_k(scores, k)                  # (T, k)
+        wts = top_s / jnp.sum(top_s, -1, keepdims=True) * cfg.routed_scaling
+        local = top_e.reshape(-1) - cfg.expert_shard * held       # (T*k,)
+        mine = (local >= 0) & (local < held)
+        group = jnp.where(mine, local, held)
+        order = jnp.argsort(group, stable=True)     # held slots first, by expert
+        sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+        tok = order // k
+        xs = xt[tok]                                              # (T*k, D)
+        ws = jnp.where(mine, wts.reshape(-1), 0.0)[order]
+    with phase("expert-compute"):
+        hidden = (jax.nn.silu(_gmm(xs, params["wg"], sizes))
+                  * _gmm(xs, params["wi"], sizes))
+        ys = _gmm(hidden, params["wo"], sizes)
+    with phase("moe-dispatch"):
+        out = jnp.zeros((t, d), jnp.float32).at[tok].add(ys * ws[:, None])
+    out = out.astype(x.dtype)
+    if cfg.n_shared_experts:
+        out = out + mlp(params["shared"], xt, "swiglu")
+    slots = jnp.sum(sizes)
+    stats = {"moe_slots_held": slots,
+             "moe_load_max": jnp.max(sizes) * held / jnp.maximum(slots, 1),
+             "moe_dropped": jnp.sum(mine.astype(jnp.int32)) - slots}
+    return out.reshape(b, s, d), stats
 
 
 def moe(params, x, cfg):

@@ -278,7 +278,7 @@ class MetricStream:
             raise ValueError(f"unknown driver {driver!r} (choose scan|loop)")
         k = inputs.num_rounds
         f = min(self.flush_every, k)
-        scan = rounds_lib._scan_jit(step_fn)
+        scan = rounds_lib.scan_jit_for(step_fn, state)
         parts = []
         for c0 in range(0, k, f):
             chunk = (inputs if f == k else

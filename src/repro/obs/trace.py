@@ -5,7 +5,8 @@ Two clocks, one vocabulary:
 * **In-jit phases** (`phase`): `jax.named_scope` annotations compiled into
   the HLO metadata, so an xprof/perfetto dump attributes device time to
   protocol phases — ``round → cohort-select → batch-select →
-  client-compute → dp-privatize → codec-encode → ef-gather/ef-scatter →
+  client-compute (→ mla-attention, moe-dispatch, expert-compute inside a
+  language-model client) → dp-privatize → codec-encode → ef-gather/ef-scatter →
   aggregate → collective → head-compute → surrogate-solve →
   round-metrics`` (`PHASES`). Scopes are free at runtime (they only label
   ops at trace time) and therefore safe on the hot path; they are applied
@@ -39,6 +40,7 @@ import jax
 # the phase names the program uses, in protocol order (DESIGN.md §13);
 # tests/test_obs.py holds every literal passed to `phase`/`scoped` to it
 PHASES = ("round", "cohort-select", "batch-select", "client-compute",
+          "mla-attention", "moe-dispatch", "expert-compute",
           "dp-privatize", "codec-encode", "ef-gather", "ef-scatter",
           "aggregate", "collective", "head-compute", "surrogate-solve",
           "round-metrics")
