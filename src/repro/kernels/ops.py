@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssca_update import ssca_update_pallas
 
@@ -36,12 +35,3 @@ def ssca_update(w, buf, grad, rho, gamma, tau: float, lam: float,
         return ref.ssca_update_ref(w, buf, grad, rho, gamma, tau, lam)
     return ssca_update_pallas(w, buf, grad, rho, gamma, tau, lam,
                               interpret=(impl == "interpret"))
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "window", "impl"))
-def flash_attention(q, k, v, causal: bool = True, window: int = 0,
-                    impl: str = "auto"):
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
-        return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
-    return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  interpret=(impl == "interpret"))

@@ -53,7 +53,8 @@ def stochastic_quantize_ref(x, bits, qmax: int, chunk: int = 256):
 
 
 def flash_attention_ref(q, k, v, *, causal=True, window=0, scale=None):
-    """q: (B,H,Sq,D); k,v: (B,KV,Sk,D); GQA via H % KV == 0. fp32 softmax."""
+    """q, k: (B,H|KV,Sq|Sk,D); v: (B,KV,Sk,Dv); GQA via H % KV == 0. fp32
+    softmax. A row that sees no key reads NaN."""
     b, h, sq, d = q.shape
     kvh, sk = k.shape[1], k.shape[2]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -71,4 +72,4 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, scale=None):
     logits = jnp.where(mask, logits, -jnp.inf)
     w = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkrqs,bksd->bkrqd", w, v.astype(jnp.float32))
-    return out.reshape(b, h, sq, d).astype(q.dtype)
+    return out.reshape(b, h, sq, v.shape[-1]).astype(q.dtype)

@@ -354,15 +354,48 @@ _cattn.defvjp(_cattn_fwd, _cattn_bwd)
 
 def chunked_attention(q, k, v, q_pos, k_pos, *, causal=True, window=0,
                       prefix_len=0, block=512):
-    """Flash-attention algorithm at the XLA level: blocked online softmax with
-    a recompute-based custom VJP. Never materializes the (Sq, Sk) logits —
-    working set is (Sq, block). This is the jnp mirror of
-    kernels/flash_attention.py (which replaces it on real TPU).
+    """Flash attention: q,k: (B,Sq|Sk,H,Hd); v: (B,Sk,H,Hv) (already
+    GQA-broadcast; Hv may differ from Hd, as in latent attention);
+    q_pos/k_pos: (1, Sq)/(1, Sk). Returns (B,Sq,H,Hv).
 
-    q,k: (B,Sq|Sk,H,Hd); v: (B,Sk,H,Hv) (already GQA-broadcast; Hv may
-    differ from Hd, as in latent attention); q_pos/k_pos: (1, Sq)/(1, Sk).
-    Returns (B,Sq,H,Hv).
+    Where the program is compiled for a TPU, causal self-attention (the
+    same positions 0..S-1 for queries and keys, no window, no prefix)
+    outside a mesh, over a sequence that whole 128-row tiles cover, runs
+    the Pallas kernel of ``kernels/flash_attention.py``, which skips the
+    tiles the mask hides. Everywhere else, and as the tests' reference, the
+    blocked online softmax of ``_chunked_scan`` runs.
     """
+    sq, sk = q.shape[1], k.shape[1]
+    scan = _functools.partial(_chunked_scan, causal=causal, window=window,
+                              prefix_len=prefix_len, block=block)
+    if (causal and not window and not prefix_len and sq == sk
+            and sq % 128 == 0 and _ambient_mesh() is None):
+        return jax.lax.platform_dependent(q, k, v, q_pos, k_pos,
+                                          tpu=_flash_kernel, default=scan)
+    return scan(q, k, v, q_pos, k_pos)
+
+
+def _flash_kernel(q, k, v, q_pos, k_pos):
+    """The causal Pallas kernel on (B,S,H,D) inputs; the products take
+    the operands XLA's would under the configured matmul precision (one
+    bfloat16 pass for float32 inputs at the default). A Mosaic kernel is
+    not partitioned by XLA, hence no mesh. Imported here: Pallas takes
+    1-2 s to import, which a program without attention need not pay."""
+    from repro.kernels.flash_attention import flash_attention_pallas
+    del q_pos, k_pos                    # 0..S-1 for both: masked by index
+    single_pass = jax.config.jax_default_matmul_precision in (
+        None, "default", "fastest", "bfloat16", "BF16_BF16_F32")
+    mxu = jnp.bfloat16 if q.dtype == jnp.float32 and single_pass else None
+    t = lambda x: x.transpose(0, 2, 1, 3)                       # (B,H,S,D)
+    return t(flash_attention_pallas(t(q), t(k), t(v), causal=True,
+                                    mxu_dtype=mxu))
+
+
+def _chunked_scan(q, k, v, q_pos, k_pos, *, causal, window, prefix_len,
+                  block):
+    """Flash-attention algorithm at the XLA level: blocked online softmax
+    with a recompute-based custom VJP. Never materializes the (Sq, Sk)
+    logits; the working set is (Sq, block)."""
     b, sq, h, hd = q.shape
     sk, hv = k.shape[1], v.shape[-1]
     blk = min(block, sk)

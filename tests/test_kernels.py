@@ -75,6 +75,114 @@ def test_flash_attention_blocks_fully_masked_rows():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
+def _attention_inputs(key, b, h, sq, sk, dqk, dv, dtype):
+    q = jax.random.normal(key, (b, h, sq, dqk)).astype(dtype)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (b, h, sk, dqk)).astype(dtype)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (b, h, sk, dv)).astype(dtype)
+    do = jax.random.normal(jax.random.fold_in(key, 3), (b, h, sq, dv)).astype(dtype)
+    return q, k, v, do
+
+
+def _scan_attention(q, k, v, q_pos, k_pos):
+    """``chunked_attention``'s jnp scan on (B, H, S, D) inputs."""
+    from repro.models import layers as L
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    return t(L.chunked_attention(t(q), t(k), t(v), q_pos, k_pos, causal=True,
+                                 block=64))
+
+
+def _rel_err(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+@pytest.mark.parametrize("dqk,dv", [(192, 128), (64, 64)])
+@pytest.mark.parametrize("block", [64, 128])
+@pytest.mark.parametrize("dtype,mxu,tol", [
+    (jnp.float32, None, 2e-5),                 # float32 products
+    (jnp.bfloat16, None, 3e-2),                # bfloat16 inputs
+    (jnp.float32, jnp.bfloat16, 2e-2),         # the TPU path: one bf16 pass
+], ids=["f32", "bf16", "f32-bf16-products"])
+def test_flash_attention_grads_match_references(dqk, dv, block, dtype, mxu,
+                                                tol):
+    """Causal forward and dq, dk, dv of the kernel (interpret mode) against
+    ``chunked_attention``'s jnp scan and ``ref.flash_attention_ref``."""
+    s = 256
+    q, k, v, do = _attention_inputs(jax.random.PRNGKey(7), 1, 2, s, s, dqk,
+                                    dv, dtype)
+    pos = jnp.arange(s, dtype=jnp.int32)[None]
+    kernel = lambda q, k, v: flash_attention_pallas(
+        q, k, v, causal=True, block_q=block, block_k=block, mxu_dtype=mxu,
+        interpret=True)
+    out, vjp = jax.vjp(kernel, q, k, v)
+    grads = vjp(do)
+    assert out.shape == (1, 2, s, dv) and out.dtype == dtype
+    assert [g.dtype for g in grads] == [dtype] * 3
+    for reference in (lambda q, k, v: _scan_attention(q, k, v, pos, pos),
+                      lambda q, k, v: ref.flash_attention_ref(q, k, v)):
+        want, want_vjp = jax.vjp(reference, q, k, v)
+        assert _rel_err(out, want) < tol
+        for g, w in zip(grads, want_vjp(do)):
+            assert _rel_err(g, w) < tol
+
+
+def test_flash_attention_fully_masked_rows_have_finite_grads():
+    """Queries right-aligned past the keys (Sq > Sk, causal): the first
+    Sq - Sk rows see no key. They read 0, and every gradient is finite and
+    equal to the jnp scan's."""
+    sq, sk = 256, 128
+    q, k, v, do = _attention_inputs(jax.random.PRNGKey(8), 1, 2, sq, sk, 192,
+                                    128, jnp.float32)
+    kernel = lambda q, k, v: flash_attention_pallas(
+        q, k, v, causal=True, block_q=64, block_k=64, interpret=True)
+    out, vjp = jax.vjp(kernel, q, k, v)
+    grads = vjp(do)
+    assert bool(jnp.all(out[:, :, :sq - sk] == 0.0))
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in (out, *grads))
+    q_pos = jnp.arange(sq, dtype=jnp.int32)[None] - (sq - sk)
+    k_pos = jnp.arange(sk, dtype=jnp.int32)[None]
+    want, want_vjp = jax.vjp(
+        lambda q, k, v: _scan_attention(q, k, v, q_pos, k_pos), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+    for g, w in zip(grads, want_vjp(do)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-5)
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_mla_attention_dispatch(platform):
+    """The grad of a small latent attention, lowered for the TPU on this
+    host, runs the three flash kernels, each under the ``mla-attention``
+    scope as the benchmark reads op names (a whole ``/`` component, here
+    inside a ``client-compute`` scope as in the round engine); lowered for
+    the CPU it runs none and keeps the jnp scan."""
+    import re
+    from repro.configs.moonlight_16b_a3b import CONFIG
+    from repro.models import layers as L
+    from repro.obs.trace import phase
+
+    cfg = CONFIG.smoke()
+    assert cfg.attention_impl == "chunked"
+    params = jax.eval_shape(
+        lambda: L.mla_init(jax.random.PRNGKey(0), cfg, jnp.float32))
+    x = jax.ShapeDtypeStruct((1, 256, cfg.d_model), jnp.float32)
+    pos = jnp.arange(256, dtype=jnp.int32)[None]
+
+    def loss(p, x):
+        with phase("client-compute"):
+            return jnp.sum(L.mla_attention(p, x, pos, cfg) ** 2)
+
+    hlo = jax.jit(jax.grad(loss)).trace(params, x).lower(
+        lowering_platforms=(platform,)).as_text(dialect="hlo", debug_info=True)
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in hlo.splitlines() if "tpu_custom_call" in line]
+    if platform == "cpu":
+        assert calls == []
+        return
+    kernels = sorted(re.search(r"/(flash_\w+)/", c).group(1) for c in calls)
+    assert kernels == ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert all("mla-attention" in c.split("/") for c in calls), calls
+
+
 def test_ops_dispatch_ref_on_cpu():
     from repro.kernels import ops
     key = jax.random.PRNGKey(4)

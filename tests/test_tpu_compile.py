@@ -115,6 +115,29 @@ def test_depth_cut_lm_scan_step_fits_one_chip(one_chip):
     assert total + 2e9 <= V5E_HBM_BYTES, total
 
 
+def test_latent_attention_kernels_compile_at_cell_size(one_chip):
+    """The grad of causal latent attention as the LM cell runs it (16
+    heads, 4 096 tokens, q.k 192 / v 128, float32 inputs, one bf16 pass)
+    takes the three flash kernels and no scan over key blocks."""
+    from repro.models import layers as L
+
+    b, s, h = 1, 4096, 16
+    q, k, v = _on(one_chip, (jax.ShapeDtypeStruct((b, s, h, 192), jnp.float32),
+                             jax.ShapeDtypeStruct((b, s, h, 192), jnp.float32),
+                             jax.ShapeDtypeStruct((b, s, h, 128), jnp.float32)))
+    pos = jnp.arange(s, dtype=jnp.int32)[None]
+
+    def loss(q, k, v):
+        return jnp.sum(L.chunked_attention(q, k, v, pos, pos, causal=True))
+
+    with jax.default_matmul_precision("bfloat16"):
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, k, v).compile().as_text()
+    assert sorted(re.findall(r"%(flash_\w+)\.?\d* = ", hlo)) == [
+        "flash_dkv", "flash_dq", "flash_fwd"]
+    assert " while(" not in hlo
+
+
 EF_CLIENTS = 203
 
 
