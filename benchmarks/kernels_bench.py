@@ -1,10 +1,7 @@
-"""Micro-benchmarks of the kernel reference paths (CPU) + roofline table from
-the dry-run artifacts. On TPU the Pallas paths replace the ref ops; wall-times
-here are CPU sanity numbers, the roofline table is the TPU-target projection."""
+"""Micro-benchmarks of the kernel reference paths (CPU). On TPU the Pallas
+paths replace the ref ops; wall-times here are CPU sanity numbers."""
 from __future__ import annotations
 
-import glob
-import json
 import time
 
 import jax
@@ -41,27 +38,3 @@ def kernel_microbench():
     v = jax.random.normal(jax.random.fold_in(key, 3), (1, 2, 512, 64))
     f = jax.jit(lambda a, b, c: ref.flash_attention_ref(a, b, c))
     print(f"kern.flash_attn.512,{_time(f, q, k, v):.0f},ref_cpu", flush=True)
-
-
-def roofline_table(result_dir="results/dryrun"):
-    """§Roofline: the per-(arch x shape x mesh) three-term table."""
-    files = sorted(glob.glob(f"{result_dir}/*.json"))
-    if not files:
-        print("roofline.table,0,no dry-run artifacts found (run scripts/dryrun_sweep.sh)")
-        return
-    print("# arch,shape,mesh,status,bottleneck,compute_ms,memory_ms,"
-          "collective_ms,useful_ratio,hbm_gb_per_dev")
-    for f in files:
-        r = json.load(open(f))
-        r = r[0] if isinstance(r, list) else r
-        if r.get("status") != "ok":
-            print(f"roofline.{r.get('arch')}.{r.get('shape')}.{r.get('mesh','?')},"
-                  f"0,{r.get('status')}:{str(r.get('why', r.get('error','')))[:40]}")
-            continue
-        mem = r.get("memory") or {}
-        peak = (mem.get("peak_bytes") or 0) / 1e9
-        print(f"roofline.{r['arch']}.{r['shape']}.{r['mesh']},0,"
-              f"{r['bottleneck']};c={r['compute_s']*1e3:.1f}ms;"
-              f"m={r['memory_s']*1e3:.1f}ms;x={r['collective_s']*1e3:.1f}ms;"
-              f"useful={r.get('useful_flop_ratio', 0):.2f};peak={peak:.2f}GB",
-              flush=True)

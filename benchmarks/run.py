@@ -1,5 +1,5 @@
-"""Benchmark harness — one function per paper table/figure plus the roofline
-table and kernel micro-benches. Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark harness — one function per paper table/figure plus the kernel
+micro-benches. Prints ``name,us_per_call,derived`` CSV.
 
 Usage:  PYTHONPATH=src python -m benchmarks.run [--quick] [--only NAME]
 """
@@ -33,7 +33,6 @@ def main() -> None:
         ("ext2_dp_uploads", extensions_bench.ext2_dp_uploads),
         ("dp_privacy_frontier", dp_bench.dp_privacy_frontier),
         ("kernel_microbench", kernels_bench.kernel_microbench),
-        ("roofline_table", kernels_bench.roofline_table),
     ]
     failed = []
     for name, fn in benches:

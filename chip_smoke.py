@@ -2,17 +2,19 @@
 """Drive the repo's main paths once on a TPU, through the library's own
 training loops, and check what comes out.
 
-    python chip_smoke.py             # one chip: LM trainer, cohort FL, feature FL
+    python chip_smoke.py             # one chip: LM job, cohort FL, feature FL
     python chip_smoke.py --chips 4   # four chips: sharded engines vs local only
 
 One chip, in order:
 
 1. device gate: JAX must find a TPU, else exit non-zero before any phase
    (there is no CPU fallback);
-2. LM trainer: ``train_loop`` on qwen2.5-3b at its published widths, bf16
-   and remat kept, depth cut to ``LM_LAYERS``, scan driver. Every loss is
-   finite, and step 1's loss matches a float32 forward of the same initial
-   params on the same batch within ``LM_RTOL`` relative;
+2. LM job: ``train_loop`` (the cross-silo LM job on the round engine) on
+   qwen2.5-3b at its published widths, bf16 and remat kept, depth cut to
+   ``LM_LAYERS``: ``LM_S`` of ``LM_SILOS`` silos a round, ``LM_BATCH``
+   rows of ``LM_SEQ`` tokens each, ``LM_ROUNDS`` rounds in one dispatch.
+   Every round's loss estimate is finite, and round 1's matches a float32
+   rebuild of it outside the engine within ``LM_RTOL`` relative;
 3. cohort engine (sample-based FL, Algorithm 1): ``cohort_train_loop`` at
    a population of 1e6 with cohorts of 256 and the int8 codec on its
    compiled Pallas quantizer (the scanned step must hold a
@@ -49,16 +51,16 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.configs import FLConfig, get_config  # noqa: E402
-from repro.core import rounds  # noqa: E402
-from repro.data.synthetic import sample_window, token_dataset  # noqa: E402
+from repro.core import fed, rounds  # noqa: E402
+from repro.data.synthetic import VirtualTokenData  # noqa: E402
 from repro.launch import train  # noqa: E402
-from repro.models import get_model  # noqa: E402
+from repro.models import transformer  # noqa: E402
 
 LM_ARCH = "qwen2.5-3b"
-# the most layers whose scan step leaves >= 2 GB of the 16 GB chip free by
-# memory_analysis() (tests/test_tpu_compile.py holds it under 16 GB)
-LM_LAYERS = 10
-LM_BATCH, LM_SEQ, LM_STEPS = 4, 512, 5
+# the most layers whose K-round program leaves >= 2 GB of the 16 GB chip
+# free by memory_analysis() (tests/test_tpu_compile.py holds it there)
+LM_LAYERS = 6
+LM_SILOS, LM_S, LM_BATCH, LM_SEQ, LM_ROUNDS = 8, 2, 2, 512, 5
 # bf16 training forward vs the float32 reference forward, relative
 LM_RTOL = 2e-2
 SEED = 0
@@ -89,25 +91,39 @@ def lm_config():
     return dataclasses.replace(get_config(LM_ARCH), n_layers=LM_LAYERS)
 
 
-def lm_reference_loss(cfg, batch: int, seq: int, seed: int = SEED) -> float:
-    """Float32 forward of ``train_loop``'s initial params on its first
-    batch: the same seed derivation as ``train_loop`` (params from
-    ``PRNGKey(seed)``, tokens from ``fold_in(key, 1)``, round keys from
-    ``fold_in(key, 2)``), params cast up to f32, no remat, highest matmul
-    precision."""
-    model = get_model(cfg)
+def lm_reference_loss(cfg, silos: int, participation: int, batch: int,
+                      seq: int, num_rounds: int, seed: int = SEED) -> float:
+    """Round 1's loss estimate of ``train_loop`` rebuilt outside the engine,
+    in float32: the population, initial params and run key as
+    ``train_loop`` derives them from ``seed`` (``fold_in(key, 0xDA7A)``,
+    ``fold_in(key, 1)``, ``fold_in(key, 2)``), round 1's key as
+    ``rounds.run_rounds`` splits it for one dispatch of ``num_rounds``
+    rounds (as ``bench/engines/lm_silo.round_keys`` does), then the
+    cohort, each silo's rows and the ``fed.cohort_weights`` weights by the
+    engine's key derivation, and sum_i w_i sum_b loss(row) one silo at a
+    time: params cast up to f32, no remat, highest matmul precision."""
     key = jax.random.PRNGKey(seed)
-    params = model.init(key, cfg)
-    toks = token_dataset(jax.random.fold_in(key, 1), cfg.vocab_size,
-                         n_tokens=max(200_000, batch * (seq + 1) * 4))
-    _, sub = jax.random.split(jax.random.fold_in(key, 2))
-    round_key = rounds.make_inputs(FLConfig(), 1, 1, sub).key[0]
-    data = sample_window(toks, round_key, batch, seq)
+    data = VirtualTokenData(jax.random.fold_in(key, 0xDA7A), silos, seq,
+                            cfg.vocab_size)
+    params = jax.tree.map(lambda x: x.astype(jnp.float32),
+                          transformer.init(jax.random.fold_in(key, 1), cfg))
+    sub = jax.random.split(jax.random.fold_in(key, 2))[1]
+    k1 = rounds.make_inputs(FLConfig(), 1, num_rounds, sub).key[0]
+    ids = fed.cohort_sample(jax.random.fold_in(k1, 0x5CA), silos,
+                            participation)
+    counts = data.counts_for(ids)
+    weights = fed.cohort_weights(counts, batch, silos, data.total)
     f32 = dataclasses.replace(cfg, dtype="float32", remat=False)
-    params = jax.tree.map(lambda x: x.astype(jnp.float32), params)
+    loss = jax.jit(lambda p, x, y: transformer.per_sequence_loss(p, x, y,
+                                                                 f32)[0])
+    total = 0.0
     with jax.default_matmul_precision("highest"):
-        loss = jax.jit(model.loss_fn, static_argnums=2)(params, data, f32)
-    return float(loss)
+        for i, n_i, w_i in zip(ids, counts, weights):
+            rows = jax.random.randint(jax.random.fold_in(k1, i), (batch,), 0,
+                                      n_i)[:min(batch, int(n_i))]
+            x, y = data.batch_rows(i[None], rows[None])
+            total += float(w_i) * float(jnp.sum(loss(params, x[0], y[0])))
+    return total
 
 
 def device_gate(count: int):
@@ -127,29 +143,28 @@ def device_gate(count: int):
 
 def lm_phase(dev):
     cfg = lm_config()
-    print(f"== LM trainer: {cfg.name} ({cfg.source}) layers={cfg.n_layers} "
+    print(f"== LM job: {cfg.name} ({cfg.source}) layers={cfg.n_layers} "
           f"d_model={cfg.d_model} heads={cfg.n_heads}/{cfg.n_kv_heads} "
           f"d_ff={cfg.d_ff} vocab={cfg.vocab_size} dtype={cfg.dtype} "
-          f"remat={cfg.remat} batch={LM_BATCH} seq={LM_SEQ} "
-          f"steps={LM_STEPS}", flush=True)
-    state, logs = train.train_loop(cfg, LM_STEPS, LM_BATCH, LM_SEQ,
-                                   log_every=1, seed=SEED, driver="scan")
-    del state
-    losses = [m["loss"] for m in logs]
-    walls = [m["wall_s"] for m in logs]
-    check(len(losses) == LM_STEPS, f"expected {LM_STEPS} losses: {losses}")
+          f"remat={cfg.remat} silos={LM_SILOS} cohort={LM_S} "
+          f"batch={LM_BATCH} seq={LM_SEQ} rounds={LM_ROUNDS}", flush=True)
+    t0 = time.perf_counter()
+    res = train.train_loop(cfg, clients=LM_SILOS, participation=LM_S,
+                           rounds=LM_ROUNDS, batch=LM_BATCH, seq=LM_SEQ,
+                           log_every=1, seed=SEED)
+    losses = [float(v) for v in res.history["round_loss_est"]]
+    run_s = time.perf_counter() - t0
+    del res
+    check(len(losses) == LM_ROUNDS, f"expected {LM_ROUNDS} losses: {losses}")
     check(all(math.isfinite(v) for v in losses), f"non-finite loss {losses}")
-    steady = (walls[-1] - walls[0]) / (len(walls) - 1)
-    compile_s = walls[0] - steady
     peak = dev.memory_stats()["peak_bytes_in_use"]
     print(f"lm losses={losses}", flush=True)
-    print(f"lm compile_s={compile_s} steady_s_per_step={steady} "
-          f"peak_bytes_in_use={peak}", flush=True)
-    ref = lm_reference_loss(cfg, LM_BATCH, LM_SEQ)
+    print(f"lm run_s={run_s} peak_bytes_in_use={peak}", flush=True)
+    ref = lm_reference_loss(cfg, LM_SILOS, LM_S, LM_BATCH, LM_SEQ, LM_ROUNDS)
     rel = abs(losses[0] - ref) / abs(ref)
-    print(f"lm step1 loss={losses[0]} f32_reference={ref} rel_diff={rel}",
+    print(f"lm round1 loss={losses[0]} f32_reference={ref} rel_diff={rel}",
           flush=True)
-    check(rel <= LM_RTOL, f"step-1 loss {losses[0]} vs f32 reference {ref}: "
+    check(rel <= LM_RTOL, f"round-1 loss {losses[0]} vs f32 reference {ref}: "
           f"relative diff {rel} > {LM_RTOL}")
 
 

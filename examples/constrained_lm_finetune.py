@@ -1,5 +1,7 @@
 """Constrained federated training of a language model — the paper's Algorithm 2
-applied to the model zoo: min ‖ω‖² s.t. mean-loss <= U (formulation (40)).
+applied to the model zoo: min ‖ω‖² s.t. mean-loss <= U (formulation (40)),
+over a virtual population of ``--clients`` token silos, ``--participation``
+of them a round, through the CLI's sample job (``launch.train.train_loop``).
 
     PYTHONPATH=src python examples/constrained_lm_finetune.py \
         --arch qwen2.5-3b --smoke --steps 120 --cost-limit 4.5
@@ -10,7 +12,9 @@ while the parameter norm shrinks (Theorem 2 behaviour on a real model).
 """
 import argparse
 
-from repro.configs.base import FLConfig
+import jax
+import jax.numpy as jnp
+
 from repro.launch.train import train_loop
 
 
@@ -20,20 +24,26 @@ def main():
     ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--full", dest="smoke", action="store_false")
     ap.add_argument("--steps", type=int, default=120)
-    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--clients", type=int, default=4)
+    ap.add_argument("--participation", type=int, default=4)
+    ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--cost-limit", type=float, default=4.5)
     args = ap.parse_args()
 
-    fl = FLConfig(a1=0.9, a2=0.5, alpha_rho=0.1, alpha_gamma=0.6, tau=0.2,
-                  constrained=True, cost_limit=args.cost_limit, penalty_c=1e4)
-    state, logs = train_loop(args.arch, args.steps, args.batch, args.seq,
-                             smoke=args.smoke, constrained=True, fl=fl,
-                             log_every=10)
-    last = logs[-1]
-    print(f"\nfinal: loss={last['loss']:.4f} (U={args.cost_limit}) "
-          f"nu={last['nu']:.3f} slack={last['slack']:.2e} l2={last['l2']:.2f}")
-    if last["loss"] <= args.cost_limit * 1.1:
+    res = train_loop(args.arch, clients=args.clients,
+                     participation=args.participation, rounds=args.steps,
+                     batch=args.batch, seq=args.seq, smoke=args.smoke,
+                     constrained=True, cost_limit=args.cost_limit,
+                     log_every=10)
+    h = res.history
+    loss = float(h["round_loss_est"][-1])
+    l2 = float(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
+                   for x in jax.tree.leaves(res.params)))
+    print(f"\nfinal: loss={loss:.4f} (U={args.cost_limit}) "
+          f"nu={float(h['round_nu'][-1]):.3f} "
+          f"slack={float(h['round_slack'][-1]):.2e} l2={l2:.2f}")
+    if loss <= args.cost_limit * 1.1:
         print("constraint satisfied — model norm minimized subject to the "
               "loss budget.")
     else:

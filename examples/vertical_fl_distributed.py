@@ -29,7 +29,6 @@ def main():
     ap.add_argument("--codec", choices=("none", "int8", "int4", "topk"),
                     default="none",
                     help="compress the head + block q-uploads")
-    ap.add_argument("--driver", choices=("scan", "loop"), default="scan")
     args = ap.parse_args()
 
     os.environ.setdefault(
@@ -43,7 +42,7 @@ def main():
     result = feature_train_loop(
         clients=args.clients, rounds=args.rounds,
         constrained=args.constrained, cost_limit=args.cost_limit,
-        topology=args.topology, codec=args.codec, driver=args.driver,
+        topology=args.topology, codec=args.codec,
         log_every=max(args.rounds // 10, 1))
     print("h-exchange per round: (I x B x J) floats all-gathered over the "
           "model axis (the paper's Alg-3 step 4); "

@@ -1,3 +1,2 @@
-from repro.configs.base import FLConfig, ModelConfig, ShapeConfig  # noqa: F401
+from repro.configs.base import FLConfig, ModelConfig  # noqa: F401
 from repro.configs.registry import ARCHS, get_config  # noqa: F401
-from repro.configs.shapes import SHAPES, input_specs, supports_shape  # noqa: F401

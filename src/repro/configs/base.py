@@ -1,8 +1,8 @@
 """Architecture/config dataclasses shared across the framework.
 
 Every assigned architecture instantiates :class:`ModelConfig` (full size) plus a
-reduced smoke variant via :func:`ModelConfig.smoke`. Input shapes are described by
-:class:`ShapeConfig` (see ``configs/shapes.py`` for the four assigned shapes).
+reduced smoke variant via :func:`ModelConfig.smoke`; :class:`FLConfig` holds the
+paper's federated-learning knobs.
 """
 from __future__ import annotations
 
@@ -122,14 +122,6 @@ class ModelConfig:
         )
         small.update(overrides)
         return dataclasses.replace(self, **small)
-
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                  # train | prefill | decode
 
 
 @dataclass(frozen=True)

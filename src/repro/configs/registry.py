@@ -20,8 +20,6 @@ ARCHS = {
     "mnist-mlp": mnist_mlp.CONFIG,           # the paper's own model
 }
 
-ASSIGNED = [k for k in ARCHS if k not in ("glm4-9b-swa", "mnist-mlp")]
-
 
 def get_config(name: str):
     try:

@@ -198,7 +198,7 @@ ENGINES = {"scan": scan_rounds, "loop": loop_rounds}
 
 def chunk_sizes(rounds: int, chunk: int):
     """Split `rounds` into chunk-sized dispatches, never dropping the partial
-    final chunk (shared invariant of run_rounds and launch/train.py)."""
+    final chunk."""
     chunk = max(1, min(chunk, rounds))
     sizes = [chunk] * (rounds // chunk)
     if rounds % chunk:

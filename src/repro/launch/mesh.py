@@ -1,12 +1,7 @@
-"""Production mesh construction.
+"""Device meshes and sharding-spec helpers.
 
 Defined as functions (never module-level constants) so importing this module
-never touches jax device state — required because smoke tests and benches run
-with 1 real CPU device while the dry-run forces 512 virtual host devices.
-
-Axes:
-  single-pod: (16, 16)      -> ("data", "model")          256 chips (one v5e pod)
-  multi-pod:  (2, 16, 16)   -> ("pod", "data", "model")   512 chips (2 pods)
+never touches jax device state.
 
 FL semantics (DESIGN.md §2): the ("pod","data") shards ARE the paper's clients;
 sample-based q-aggregation is the all-reduce over those axes. The "model" axis
@@ -14,24 +9,8 @@ carries tensor/expert parallelism (and the feature-based ω_i blocks).
 """
 from __future__ import annotations
 
-import math
-
 import jax
 from jax.sharding import PartitionSpec as P
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = math.prod(shape)
-    devices = jax.devices()
-    if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices for the production mesh, have {len(devices)}; "
-            "run via launch/dryrun.py which forces 512 host devices")
-    import numpy as np
-    dev_array = np.asarray(devices[:n]).reshape(shape)
-    return jax.sharding.Mesh(dev_array, axes)
 
 
 def make_client_mesh(num_devices: int | None = None, axis: str = "data"):
@@ -120,10 +99,6 @@ def fit_specs(spec_tree, shape_tree, mesh):
 
     return jax.tree.map(fit, spec_tree, shape_tree,
                         is_leaf=lambda x: isinstance(x, P))
-
-
-def named_fitted(mesh, spec_tree, shape_tree):
-    return named(mesh, fit_specs(spec_tree, shape_tree, mesh))
 
 
 def named(mesh, spec_tree):

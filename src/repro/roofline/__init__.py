@@ -1,2 +1,1 @@
-from repro.roofline.analysis import (HW, collective_bytes_from_hlo,  # noqa: F401
-                                     model_flops, roofline_terms)
+from repro.roofline.analysis import HW, roofline_terms  # noqa: F401

@@ -1,5 +1,6 @@
 """chip_smoke.py's own logic, checked on the CPU: its float32 reference
-rebuilds the LM trainer's first batch and initial params exactly, and it
+rebuilds the LM job's first round (cohort, rows, weights, initial params)
+exactly, and it
 refuses to run (exit non-zero, no result line) where JAX finds no TPU."""
 import dataclasses
 import importlib.util
@@ -23,13 +24,15 @@ _spec.loader.exec_module(chip_smoke)
 @pytest.mark.parametrize("seed", [0, 3])
 def test_reference_loss_matches_train_loop_first_step(seed):
     """Both sides compute in f32 at a smoke size, so they agree far inside
-    the tolerance; a different batch or init would move the loss by ~1e-2
-    (the step-to-step spread at this size)."""
+    the tolerance; another cohort, batch or init would move the loss by
+    ~1e-2 (the round-to-round spread at this size)."""
     cfg = dataclasses.replace(get_config(chip_smoke.LM_ARCH).smoke(),
                               remat=True)
-    _, logs = train.train_loop(cfg, 1, 2, 16, log_every=1, seed=seed)
-    ref = chip_smoke.lm_reference_loss(cfg, 2, 16, seed=seed)
-    assert logs[0]["loss"] == pytest.approx(ref, rel=1e-5)
+    res = train.train_loop(cfg, clients=4, participation=2, rounds=2,
+                           batch=2, seq=16, seed=seed)
+    ref = chip_smoke.lm_reference_loss(cfg, 4, 2, 2, 16, 2, seed=seed)
+    assert float(res.history["round_loss_est"][0]) == pytest.approx(
+        ref, rel=1e-5)
 
 
 def test_refuses_without_tpu():

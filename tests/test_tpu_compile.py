@@ -24,9 +24,9 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
 
 from repro.comm.codecs import make_codec, tree_flat_dim
 from repro.configs import FLConfig
-from repro.core import optimizer, rounds
-from repro.launch import train
-from repro.models import get_model, mlp
+from repro.core import algorithms, optimizer, rounds
+from repro.data.synthetic import VirtualTokenData
+from repro.models import get_model, mlp, transformer
 
 _spec = importlib.util.spec_from_file_location(
     "chip_smoke", Path(__file__).resolve().parents[1] / "chip_smoke.py")
@@ -96,20 +96,29 @@ def test_quantizer_compiles_under_vmap_at_cohort_dim(one_chip):
 
 
 def test_depth_cut_lm_scan_step_fits_one_chip(one_chip):
-    """chip_smoke's LM trainer step (qwen2.5-3b at published widths, depth
-    cut, bf16 + remat, batch x seq as run there) leaves >= 2 GB of the
-    chip's 16 GB free by memory_analysis()."""
+    """chip_smoke's LM job as ``train_loop`` runs it: Algorithm 1 through
+    the cohort engine with ``per_sequence_loss`` over a ``VirtualTokenData``
+    population (qwen2.5-3b at published widths, depth cut, bf16 + remat;
+    silos, cohort, rows and rounds as run there). Its state is large enough
+    that the chip donates it to the K-round program, and that program
+    leaves >= 2 GB of the chip's 16 GB free by memory_analysis()."""
     cfg = chip_smoke.lm_config()
-    model = get_model(cfg)
-    fl = FLConfig()
-    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), cfg))
+    fl = FLConfig(batch_size=chip_smoke.LM_BATCH)
+    data = VirtualTokenData(jax.random.PRNGKey(0), chip_smoke.LM_SILOS,
+                            chip_smoke.LM_SEQ, cfg.vocab_size)
+    step = algorithms.make_algorithm1_step(
+        lambda p, x, y: transformer.per_sequence_loss(p, x, y, cfg), data,
+        fl, participation=chip_smoke.LM_S, cohort=True)
+    params = jax.eval_shape(lambda: transformer.init(jax.random.PRNGKey(0),
+                                                     cfg))
     state = _on(one_chip, jax.eval_shape(optimizer.ssca_init, params))
     inputs = _on(one_chip, jax.eval_shape(
-        lambda: rounds.make_inputs(fl, 1, 1, jax.random.PRNGKey(0))))
-    tokens = jnp.zeros((200_000,), jnp.int32)
-    step = train.make_scanned_step(model, cfg, fl, tokens,
-                                   chip_smoke.LM_BATCH, chip_smoke.LM_SEQ)
-    m = rounds._scan_jit(step).lower(state, inputs).compile().memory_analysis()
+        lambda: rounds.make_inputs(fl, 1, chip_smoke.LM_ROUNDS,
+                                   jax.random.PRNGKey(0))))
+    nbytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert nbytes > rounds.DONATE_SHARE * V5E_HBM_BYTES, nbytes
+    m = rounds._scan_jit_donated(step).lower(
+        state, inputs).compile().memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert total + 2e9 <= V5E_HBM_BYTES, total
