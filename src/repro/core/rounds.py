@@ -11,12 +11,15 @@ rounds, so a K-round epoch is ONE dispatch:
     inputs = make_inputs(fl, t0, K, key)         # per-round (key, ρ^t, γ^t)
     state, hist = scan_rounds(step_fn, state, inputs)
 
-Per-round ρ^t/γ^t are precomputed on the host (including the paper's ρ^(1)=1
-convention) and threaded through the scan as stacked inputs alongside the
+Per-round ρ^t/γ^t are computed ahead of the scan (including the paper's
+ρ^(1)=1 convention) and threaded through it as stacked inputs alongside the
 per-round PRNG keys; the round counter t rides in the optimizer state as the
 scan carry. Every step emits a metrics dict of scalars, which the scan stacks
 into (K,)-arrays — full per-round trajectories for free, where the Python
-loop only saw chunk boundaries.
+loop only saw chunk boundaries. `run_rounds` builds each chunk's inputs in
+one compiled call (`chunk_inputs`) and keeps the history series that start
+on the host there, so between two dispatches the device waits on no eager
+op of the driver.
 
 ``loop_rounds`` is the semantics-identical per-round-dispatch reference used
 by the equivalence test (tests/test_rounds.py) and the scan-vs-loop
@@ -33,11 +36,13 @@ resharded by the first shard_map entry.
 """
 from __future__ import annotations
 
+import functools
 import weakref
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import schedules
 from repro.obs import trace as obs_trace
@@ -62,18 +67,50 @@ class RoundInputs(NamedTuple):
 def schedule_arrays(fl, t_start: int, num_rounds: int):
     """(ρ^t, γ^t) for t = t_start .. t_start+K-1, with the paper's ρ^(1) = 1
     convention applied (§III-A, before eq. (11)) — matches optimizer._sched."""
-    t = jnp.arange(t_start, t_start + num_rounds)
+    t = t_start + jnp.arange(num_rounds, dtype=jnp.int32)
     rho = jnp.where(t == 1, 1.0, schedules.rho(t, fl.a1, fl.alpha_rho))
     gamma = schedules.gamma(t, fl.a2, fl.alpha_gamma)
     return rho, gamma
 
 
 def make_inputs(fl, t_start: int, num_rounds: int, key) -> RoundInputs:
+    """The K = num_rounds rounds' inputs from t_start on. ``t_start`` and
+    the schedule constants of ``fl`` may be traced (`chunk_inputs`)."""
     rho, gamma = schedule_arrays(fl, t_start, num_rounds)
     return RoundInputs(key=jax.random.split(key, num_rounds),
                        rho=rho, gamma=gamma,
-                       t=jnp.arange(t_start, t_start + num_rounds,
-                                    dtype=jnp.int32))
+                       t=t_start + jnp.arange(num_rounds, dtype=jnp.int32))
+
+
+class Schedule(NamedTuple):
+    """The schedule constants of an FLConfig, passed to `chunk_inputs`'
+    program as traced values: as compile-time constants the compiler
+    specialises t**alpha for some exponents (t**1.0 -> t, t**2.0 -> t*t),
+    and the compiled schedule would no longer equal `make_inputs`' eager
+    one bit for bit."""
+    a1: float
+    alpha_rho: float
+    a2: float
+    alpha_gamma: float
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_inputs_jit(num_rounds: int):
+    """One program per chunk size K; t_start and the schedule are traced,
+    so successive chunks of one size never recompile."""
+    def chunk(key, t_start, sched):
+        key, sub = jax.random.split(key)
+        return key, make_inputs(sched, t_start, num_rounds, sub)
+    return jax.jit(chunk)
+
+
+def chunk_inputs(fl, key, t_start: int, num_rounds: int):
+    """``(next_key, inputs)``: `run_rounds`' per-chunk split of its key and
+    `make_inputs` on the split-off half, as ONE compiled call — bit for bit
+    what ``key, sub = jax.random.split(key)`` and
+    ``make_inputs(fl, t_start, num_rounds, sub)`` give eagerly."""
+    sched = Schedule(fl.a1, fl.alpha_rho, fl.a2, fl.alpha_gamma)
+    return _chunk_inputs_jit(num_rounds)(key, t_start, sched)
 
 
 def scan_rounds(step_fn: Callable, state, inputs: RoundInputs):
@@ -234,6 +271,22 @@ def _emit_eval(obs, metrics, t_global: int):
     obs.emit_event(row)
 
 
+def _host_array(values):
+    """``values`` as a host array, in the dtype ``jnp.asarray`` would give."""
+    values = np.asarray(values)
+    return values.astype(jax.dtypes.canonicalize_dtype(values.dtype),
+                         copy=False)
+
+
+def _series(values):
+    """One eval series: values the eval hook returned as device arrays are
+    stacked on the device once; host values (the round counts, Python
+    floats) stay on the host."""
+    if any(isinstance(v, jax.Array) for v in values):
+        return jnp.asarray(values)
+    return _host_array(values)
+
+
 def run_rounds(step_fn: Callable, state, fl, key, rounds: int,
                eval_fn: Optional[Callable] = None, eval_every: int = 0,
                extract_params: Optional[Callable] = None,
@@ -274,12 +327,11 @@ def run_rounds(step_fn: Callable, state, fl, key, rounds: int,
     per_round: list = []
     t0 = t_start
     # host spans (obs/trace.DRIVER_SPANS) put each device idle gap down to
-    # the driver's work in it: eager schedule ops, the K-round enqueue, the
+    # the driver's work in it: the inputs call, the K-round enqueue, the
     # eval hook's host read, the history assembly
     for size in sizes:
         with obs_trace.host_span("rounds/inputs"):
-            key, sub = jax.random.split(key)
-            inputs = make_inputs(fl, t0, size, sub)
+            key, inputs = chunk_inputs(fl, key, t0, size)
         with obs_trace.host_span("rounds/launch", rounds=size, t=t0):
             if obs is not None:
                 state, ms = obs.run(step_fn, state, inputs, driver=driver)
@@ -297,12 +349,13 @@ def run_rounds(step_fn: Callable, state, fl, key, rounds: int,
                 if obs is not None:
                     _emit_eval(obs, metrics, t0 - 1)
     with obs_trace.host_span("rounds/history"):
-        history = {k: jnp.asarray(v) for k, v in hist.items()}
+        history = {k: _series(v) for k, v in hist.items()}
         if per_round and per_round[0]:
             for k in per_round[0]:
-                history["round_" + k] = jnp.concatenate(
-                    [m[k] for m in per_round])
-            history["round_t"] = jnp.arange(t_start, t0)
+                ms = [m[k] for m in per_round]
+                history["round_" + k] = (ms[0] if len(ms) == 1
+                                         else jnp.concatenate(ms))
+            history["round_t"] = _host_array(np.arange(t_start, t0))
     return RunResult(extract_params(state), history, state)
 
 

@@ -14,17 +14,24 @@ a theory-compliant alpha_gamma = 0.6 and expose the paper's values in configs.
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
+
+
+def _power_law(a, t, alpha):
+    t = jnp.maximum(t, 1).astype(jnp.float32)
+    # the barrier keeps a compiled program from rewriting a / t**alpha as
+    # a * t**(-alpha), which rounds differently: with alpha passed in as a
+    # traced value, the compiled schedule equals the eager one bit for bit
+    return jnp.minimum(a / lax.optimization_barrier(t**alpha), 1.0)
 
 
 def rho(t, a1: float, alpha: float):
     """t is 1-based. Returns rho^(t) clipped to (0, 1]."""
-    t = jnp.maximum(t, 1).astype(jnp.float32)
-    return jnp.minimum(a1 / t**alpha, 1.0)
+    return _power_law(a1, t, alpha)
 
 
 def gamma(t, a2: float, alpha: float):
-    t = jnp.maximum(t, 1).astype(jnp.float32)
-    return jnp.minimum(a2 / t**alpha, 1.0)
+    return _power_law(a2, t, alpha)
 
 
 def check_conditions(a1, a2, alpha_rho, alpha_gamma, strict=True):

@@ -1,9 +1,13 @@
 """Scan-round driver + heterogeneous-protocol invariants: scan==loop
 trajectories, unbiased partial-participation aggregation, exactly-once
 Dirichlet partitioning, and ragged-masked == dense batch selection."""
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from jax.extend.core import Primitive
 
 from repro.configs.base import FLConfig
 from repro.core import algorithms, fed, optimizer, rounds
@@ -104,6 +108,147 @@ def test_run_rounds_eval_chunking_histories():
     assert r.history["round_loss_est"].shape == (40,)
     np.testing.assert_array_equal(np.asarray(r.history["round_t"]),
                                   np.arange(1, 41))
+
+
+# ---------------------------------------------------------------------------
+# the driver's per-chunk work: one compiled inputs call, history on the host
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("sched", [
+    {},                                          # the paper's exponents
+    {"alpha_rho": 1.0, "alpha_gamma": 0.5},      # exponents XLA specialises
+])
+@pytest.mark.parametrize("k", [1, 4, 20])
+@pytest.mark.parametrize("t_start", [1, 2, 21, 1001])
+def test_chunk_inputs_match_eager_formula(t_start, k, sched):
+    """The compiled per-chunk program gives bit for bit the driver's eager
+    ``split`` + `make_inputs`: keys, ρ (with ρ^1 = 1), γ and t."""
+    fl, key = _fl(**sched), jax.random.PRNGKey(7)
+    next_key, inp = rounds.chunk_inputs(fl, key, t_start, k)
+    want_key, sub = jax.random.split(key)
+    want = rounds.make_inputs(fl, t_start, k, sub)
+    np.testing.assert_array_equal(np.asarray(next_key), np.asarray(want_key))
+    for got, ref in zip(inp, want):
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    np.testing.assert_array_equal(np.asarray(inp.t),
+                                  np.arange(t_start, t_start + k))
+    if t_start == 1:
+        assert float(inp.rho[0]) == 1.0
+
+
+def test_chunk_inputs_compile_once_per_chunk_size():
+    """Successive chunks of one size (another t_start, another schedule)
+    reuse one compiled program."""
+    key = jax.random.PRNGKey(0)
+    key, _ = rounds.chunk_inputs(_fl(), key, 1, 7)
+    rounds.chunk_inputs(_fl(alpha_gamma=0.8), key, 8, 7)
+    assert rounds._chunk_inputs_jit(7)._cache_size() == 1
+
+
+def _eager_driver(step, state, fl, key, num_rounds, eval_fn, every):
+    """`run_rounds` as the eager formula: split + make_inputs per chunk, and
+    the history assembled with jnp on the device."""
+    hist, per_round, t0 = {"round": []}, [], 1
+    for size in rounds.chunk_sizes(num_rounds, every):
+        key, sub = jax.random.split(key)
+        state, ms = rounds.scan_rounds(step, state,
+                                       rounds.make_inputs(fl, t0, size, sub))
+        t0 += size
+        per_round.append(ms)
+        for k, v in eval_fn(state.params, state).items():
+            hist.setdefault(k, []).append(v)
+        hist["round"].append(t0 - 1)
+    history = {k: jnp.asarray(v) for k, v in hist.items()}
+    for k in per_round[0]:
+        history["round_" + k] = jnp.concatenate([m[k] for m in per_round])
+    history["round_t"] = jnp.arange(1, t0)
+    return history
+
+
+@pytest.mark.parametrize("every", [12, 3])      # one chunk, four chunks
+def test_run_rounds_history_matches_eager_formula(every):
+    """Keys, shapes, dtypes and values of the history — eval series from
+    Python floats and from device arrays, "round", "round_t" and every
+    per-round series — are those of the eager formula."""
+    z, y = _data(jax.random.PRNGKey(0))
+    params0 = mlp.init(jax.random.PRNGKey(1), P, J, L)
+    fl = _fl()
+    step = algorithms.make_algorithm1_step(
+        psl, fed.partition_samples(z, y, 4), fl)
+    state0 = optimizer.ssca_init(params0)
+
+    def eval_fn(params, state):
+        return {"loss": float(mlp.mean_loss(params, z, y)),
+                "w_norm": jnp.sqrt(sum(jnp.sum(x * x) for x in
+                                       jax.tree.leaves(params)))}
+
+    got = rounds.run_rounds(step, state0, fl, jax.random.PRNGKey(2), 12,
+                            eval_fn=eval_fn, eval_every=every).history
+    want = _eager_driver(step, state0, fl, jax.random.PRNGKey(2), 12,
+                         eval_fn, every)
+    assert sorted(got) == sorted(want)
+    assert got["round"].shape == got["loss"].shape == (12 // every,)
+    for k in want:
+        assert got[k].shape == want[k].shape, k
+        assert got[k].dtype == want[k].dtype, k
+        np.testing.assert_array_equal(np.asarray(got[k]),
+                                      np.asarray(want[k]), err_msg=k)
+
+
+def test_run_rounds_runs_no_eager_op_between_chunks(monkeypatch):
+    """Between one chunk's eval and the next chunk's enqueue, a warmed
+    `run_rounds` binds no JAX primitive in Python: the inputs call and the
+    K-round program take jit's compiled fast path, and the history of a
+    one-chunk call (the benchmark's dispatch) touches no device. A
+    multi-chunk call concatenates its per-round series once, at the end,
+    under ``rounds/history``."""
+    z, y = _data(jax.random.PRNGKey(0))
+    fl = _fl()
+    step = algorithms.make_algorithm1_step(
+        psl, fed.partition_samples(z, y, 4), fl)
+    loss = jax.jit(mlp.mean_loss)
+
+    def eval_fn(params, state):
+        return {"loss": float(loss(params, z, y))}
+
+    keys = list(jax.random.split(jax.random.PRNGKey(2), 4))
+
+    def run(state, t_start, num_rounds, every):
+        return rounds.run_rounds(step, state, fl, keys.pop(), num_rounds,
+                                 eval_fn=eval_fn, eval_every=every,
+                                 t_start=t_start)
+
+    state = optimizer.ssca_init(mlp.init(jax.random.PRNGKey(1), P, J, L))
+    state = run(state, 1, 8, 2).final_state          # warm every shape
+    state = run(state, 9, 2, 2).final_state
+
+    spans, binds = [None], []
+    span = rounds.obs_trace.host_span
+
+    @contextlib.contextmanager
+    def recording_span(name, **attrs):
+        spans.append(name)
+        try:
+            with span(name, **attrs):
+                yield
+        finally:
+            spans.pop()
+
+    bind = Primitive.bind
+
+    def recording_bind(self, *args, **params):
+        binds.append((spans[-1], self.name))
+        return bind(self, *args, **params)
+
+    monkeypatch.setattr(rounds.obs_trace, "host_span", recording_span)
+    monkeypatch.setattr(Primitive, "bind", recording_bind)
+    state = run(state, 11, 2, 2).final_state         # one chunk
+    assert binds == []
+    run(state, 13, 8, 2)                             # four chunks
+    assert {s for s, _ in binds} <= {"rounds/history"}, binds
+    assert rounds._chunk_inputs_jit(2)._cache_size() == 1
 
 
 # ---------------------------------------------------------------------------
